@@ -27,7 +27,6 @@ MODULES = {
     "fig3": "benchmarks.bench_skew_sweep",
     "fig5": "benchmarks.bench_mesh_sweep",
     "kernels": "benchmarks.bench_kernels",
-    "perf-ablation": "benchmarks.bench_perf_ablation",
     "roofline": "benchmarks.bench_roofline",
     "serve": "benchmarks.bench_serve",
 }
@@ -44,7 +43,10 @@ def main(argv: list[str] | None = None) -> None:
 
     import importlib
 
+    from repro.launch.cache import place_compile_cache
     from repro.obs import metrics as obs_metrics
+
+    place_compile_cache()
 
     reg = obs_metrics.registry()
     failures = []

@@ -45,7 +45,12 @@ from repro.api.report import RunReport, modeled_comm_words
 from repro.api.spec import ExperimentSpec, MeshSpec
 from repro.core import faults
 from repro.core.comm import MESH, TIMED, CommLedger, time_dispatch, time_phase
-from repro.core.engine import engine_comm_ledger, engine_loss, run_engine_chunk
+from repro.core.engine import (
+    engine_comm_ledger,
+    engine_loss,
+    lower_engine_chunk,
+    run_engine_chunk,
+)
 from repro.core.distributed import HybridDriver
 from repro.core.problem import problem_loss
 from repro.core.teams import global_problem
@@ -213,6 +218,16 @@ class Session:
         if self._driver is not None:
             return self._driver.gather()
         return np.asarray(self._x)
+
+    def compile_round(self) -> jax.stages.Compiled:
+        """The compiled program that advances this session one round on
+        its backend — ``as_text()`` shows which kernels and collectives
+        the round runs. Compiling it leaves the session's state as is."""
+        if self._driver is not None:
+            return self._driver.lower_step().compile()
+        return lower_engine_chunk(
+            self.bundle.team, self._x, 1, self.spec.schedule
+        ).compile()
 
     # ---- the incremental core ----
 

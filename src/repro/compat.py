@@ -1,190 +1,71 @@
-"""Single point of contact with version-dependent JAX APIs.
+"""The mesh/shard_map surface the codebase uses, over JAX's explicit-mesh
+API (``jax.shard_map``, ``jax.make_mesh(axis_types=...)``,
+``jax.sharding.set_mesh`` / ``get_abstract_mesh``).
 
-The repo targets two JAX generations:
-
-  * jax >= 0.6 — ``jax.shard_map(..., axis_names=..., check_vma=...)``,
-    explicit meshes (``jax.make_mesh(..., axis_types=...)``,
-    ``jax.sharding.set_mesh`` / ``get_abstract_mesh``).
-  * jax 0.4.x — ``jax.experimental.shard_map.shard_map(..., auto=...,
-    check_rep=...)``, legacy ``with mesh:`` contexts, no axis types.
-
-Everything else in the codebase imports the mesh/shard_map surface from
-here, never from ``jax`` directly, so the solver engine and the NN
-trainer run unmodified on both generations.
+Thin wrappers only: they fix the defaults this repo relies on (all mesh
+axes Auto, shard_map's VMA check off) in one place.
 """
 
 from __future__ import annotations
 
 import contextlib
-import threading
 
 import jax
 
-HAS_JAX_SHARD_MAP = hasattr(jax, "shard_map")
-HAS_EXPLICIT_MESH = hasattr(jax.sharding, "set_mesh")
-HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-# Trace-time stack of manual axis-name sets (legacy JAX only): the
-# enclosing shard_map's manual axes cannot appear in a sharding
-# constraint, and old meshes carry no axis_types to recover them from.
-_local = threading.local()
-
-
-def _manual_stack() -> list[frozenset[str]]:
-    if not hasattr(_local, "stack"):
-        _local.stack = []
-    return _local.stack
-
 
 def manual_axes(mesh=None) -> frozenset[str]:
-    """Axis names currently Manual: from mesh.axis_types on new JAX,
-    from the compat shard_map trace stack on old JAX."""
-    if HAS_AXIS_TYPES and mesh is not None and hasattr(mesh, "axis_types"):
-        return frozenset(
-            name
-            for name, ty in zip(mesh.axis_names, mesh.axis_types)
-            if ty == jax.sharding.AxisType.Manual
-        )
-    acc: frozenset[str] = frozenset()
-    for s in _manual_stack():
-        acc = acc | s
-    return acc
-
-
-def shard_map(f, mesh, in_specs, out_specs, axis_names=None, check=False):
-    """Version-portable shard_map.
-
-    ``axis_names``: the *manual* axes (None = all mesh axes manual).
-    ``check``: replication/VMA checking (off by default — the hybrid
-    schedules intentionally let per-team params drift).
-    """
-    if HAS_JAX_SHARD_MAP:
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check)
-        if axis_names is not None:
-            kwargs["axis_names"] = frozenset(axis_names)
-        return jax.shard_map(f, **kwargs)
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    manual = frozenset(axis_names) if axis_names is not None else frozenset(mesh.axis_names)
-    auto = frozenset(mesh.axis_names) - manual
-
-    def traced(*args, **kw):
-        _manual_stack().append(manual)
-        try:
-            return f(*args, **kw)
-        finally:
-            _manual_stack().pop()
-
-    return _shard_map(
-        traced, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check, auto=auto
+    """Axis names of ``mesh`` whose axis type is Manual."""
+    if mesh is None:
+        return frozenset()
+    return frozenset(
+        name
+        for name, ty in zip(mesh.axis_names, mesh.axis_types)
+        if ty == jax.sharding.AxisType.Manual
     )
 
 
+def shard_map(f, mesh, in_specs, out_specs, axis_names=None, check=False):
+    """``jax.shard_map`` with the replication (VMA) check off by default
+    — the hybrid schedules intentionally let per-team params drift.
+
+    ``axis_names``: the *manual* axes (None = all mesh axes manual).
+    """
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check)
+    if axis_names is not None:
+        kwargs["axis_names"] = frozenset(axis_names)
+    return jax.shard_map(f, **kwargs)
+
+
 def axis_size(name) -> int:
-    """Static size of a named mesh axis inside shard_map (jax.lax
-    .axis_size where available, the tracing axis env otherwise)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    from jax._src import core as _core
-
-    return int(_core.axis_frame(name))  # 0.4.x: returns the size
-
-
-# ---------------------------------------------------------------------------
-# mesh construction / ambient mesh
-# ---------------------------------------------------------------------------
+    """Static size of a named mesh axis inside shard_map."""
+    return jax.lax.axis_size(name)
 
 
 def abstract_mesh(shape, axes):
-    """jax.sharding.AbstractMesh across both constructor generations
-    ((sizes, names) on new JAX, ((name, size), ...) pairs on 0.4.x)."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    """A ``jax.sharding.AbstractMesh`` of ``shape`` over ``axes``."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_mesh(shape, axes, devices=None):
-    """jax.make_mesh with Auto axis types where supported."""
-    if HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            shape, axes, devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    return jax.make_mesh(shape, axes, devices=devices)
-
-
-class _AmbientMesh:
-    """Fallback global for jax.sharding.set_mesh/get_abstract_mesh."""
-
-    def __init__(self):
-        self.mesh = None
-
-
-_ambient = _AmbientMesh()
-
-
-class _EmptyMesh:
-    empty = True
-    axis_names: tuple = ()
-    axis_sizes: tuple = ()
+    """``jax.make_mesh`` with every axis Auto."""
+    return jax.make_mesh(
+        shape, axes, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def get_abstract_mesh():
-    """The ambient mesh (an object with .empty/.axis_names/.axis_sizes)."""
-    if HAS_EXPLICIT_MESH:
-        return jax.sharding.get_abstract_mesh()
-    if _ambient.mesh is not None:
-        return _ambient.mesh
-    return _EmptyMesh()
-
-
-class _SetMeshHandle:
-    """Mimics jax.sharding.set_mesh: applies immediately, optionally
-    usable as a context manager to restore the previous mesh."""
-
-    def __init__(self, mesh, prev):
-        self._mesh = mesh
-        self._prev = prev
-        self._ctx = None
-        if mesh is not None:
-            self._ctx = mesh.__enter__()  # legacy `with mesh:` context
-
-    def __enter__(self):
-        return self._mesh
-
-    def __exit__(self, *exc):
-        if self._ctx is not None:
-            args = exc if len(exc) == 3 else (None, None, None)
-            self._mesh.__exit__(*args)
-            self._ctx = None
-        _ambient.mesh = self._prev
-        return False
+    """The ambient mesh (``.empty`` / ``.axis_names`` / ``.axis_sizes``)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def set_mesh(mesh):
-    """Set the ambient mesh (jax.sharding.set_mesh where available)."""
-    if HAS_EXPLICIT_MESH:
-        return jax.sharding.set_mesh(mesh)
-    prev = _ambient.mesh
-    _ambient.mesh = mesh
-    return _SetMeshHandle(mesh, prev)
+    """Set the ambient mesh (``jax.sharding.set_mesh``)."""
+    return jax.sharding.set_mesh(mesh)
 
 
 @contextlib.contextmanager
 def use_mesh(mesh):
     """Scoped ambient mesh — always restores on exit."""
-    handle = set_mesh(mesh)
-    try:
+    with jax.sharding.set_mesh(mesh):
         yield mesh
-    finally:
-        if not HAS_EXPLICIT_MESH:
-            handle.__exit__(None, None, None)
-        elif hasattr(handle, "__exit__"):
-            handle.__exit__(None, None, None)

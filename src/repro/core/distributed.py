@@ -209,11 +209,6 @@ def _build_round_fn(prob: Hybrid2DProblem, sched: ParallelSGDSchedule,
     bundles = sched.tau // s
     objective = prob.objective
     lam = objective.l2
-    # "pallas" is the simulated engine's default; inside shard_map the
-    # same math runs on the blocked panel-streaming path (shard_map-safe
-    # everywhere, incl. CPU interpret containers).
-    gram_ = "blocked" if sched.gram == "pallas" else sched.gram
-    bk_ = sched.bk
 
     def round_fn(idx_blk, val_blk, x_loc, round_idx):
         # shapes inside shard_map: idx/val (1, 1, rows_local, width),
@@ -239,7 +234,6 @@ def _build_round_fn(prob: Hybrid2DProblem, sched: ParallelSGDSchedule,
             x_loc = delayed_bundle_scan(
                 x_loc, slice_bundle=slice_bundle, bundles=bundles, n=n_loc,
                 sched=sched, eta=eta_, objective=objective, comm=comm,
-                gram=gram_,
             )
             return comm.allmean_rows(x_loc)
 
@@ -253,7 +247,7 @@ def _build_round_fn(prob: Hybrid2DProblem, sched: ParallelSGDSchedule,
             # words under the precision knob — the psum sums narrow
             # payloads, corrections run on the f32 upcast)
             g_part, v_part = bundle_gram_v(
-                bi, bv, x_loc, n_loc, gram=gram_, bk=bk_, bm=sched.bm,
+                bi, bv, x_loc, n_loc, gram=sched.gram, bk=sched.bk, bm=sched.bm,
                 precision=sched.precision,
             )
             g, v = comm.allreduce_cols(
@@ -321,9 +315,9 @@ def make_hybrid_step(
     average) under shard_map on ``mesh`` (axes "rows", "cols").
 
     ``sched`` is the same ``ParallelSGDSchedule`` the simulated engine
-    consumes; its ``gram`` selects the bundle backend (a schedule-level
-    "pallas" is executed as "blocked" here — identical math, and the
-    panel-streaming jnp path is safe inside shard_map on every backend).
+    consumes; its ``gram`` selects the bundle backend, which runs per
+    shard exactly as on the simulated engine (the Pallas kernel by
+    default, compiled on a TPU).
     All collectives are issued through ``comm`` (repro.core.comm; the
     mesh/timed kinds run the same psum/pmean this module always issued).
 
@@ -429,6 +423,13 @@ class HybridDriver:
             jnp.asarray(scatter_x(np.asarray(x0), cp, prob.n_loc)), self._x_sh
         )
 
+    def lower_step(self) -> jax.stages.Lowered:
+        """The jitted one-round step ``advance`` dispatches, lowered but
+        not run — to read its compiled HLO or memory analysis."""
+        return self._step.lower(
+            self._idx, self._val, self._x_pad, jnp.int32(self.rounds_done)
+        )
+
     def advance(self, k: int) -> None:
         """Run ``k`` rounds; weights stay device-resident (async).
         Timed collectives block per round and record wall seconds."""
@@ -489,14 +490,13 @@ class HybridDriver:
         sched, prob, mesh = self.sched, self.prob, self._mesh
         sb = sched.s * sched.b
         bundles = sched.tau // sched.s
-        gram_ = "blocked" if sched.gram == "pallas" else sched.gram
         reps = -(-sb // prob.rows_local)
         bi = jnp.tile(prob.indices[0, 0], (reps, 1))[:sb]
         bv = jnp.tile(prob.values[0, 0], (reps, 1))[:sb]
         x_loc = jnp.zeros((prob.n_loc,), jnp.float32)
         compute = jax.jit(
             lambda i, v, x: bundle_gram_v(
-                i, v, x, prob.n_loc, gram=gram_, bk=sched.bk, bm=sched.bm,
+                i, v, x, prob.n_loc, gram=sched.gram, bk=sched.bk, bm=sched.bm,
                 precision=sched.precision,
             )
         )

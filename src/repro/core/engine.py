@@ -76,8 +76,9 @@ class ParallelSGDSchedule:
     loss_every   sample the full objective every this many rounds
                  (0 = never; the returned loss trace is then empty).
     gram    bundle (G, v) backend: "pallas" (scatter-free ELL kernel,
-            the production path), "blocked" (same math as pure jnp —
-            what shard_map uses), "dense" (the retired densify oracle,
+            the production path on both backends), "blocked" (same
+            math as pure jnp — the kernel's XLA twin), "dense" (the
+            retired densify oracle,
             kernels/ref.py — tests only; also what the profile-driven
             auto-select picks for heavy-tailed ELL widths).
     bk      column-panel width for the Gram kernels. ``None`` opts into
@@ -92,8 +93,6 @@ class ParallelSGDSchedule:
             fp32-accumulate, and the per-bundle (G, v) Allreduce ships
             bf16 words (half the β·bytes payload; word counts, and
             hence the Table 2–3 closed forms, are unchanged).
-    interpret   Pallas interpret mode — True off-TPU (this container),
-            False for the compiled Mosaic kernel on real hardware.
     p_c     column shards. Communication-only: it never changes the
             numerics (kept here so one object describes the full mesh;
             repro.core.distributed consumes it).
@@ -119,7 +118,6 @@ class ParallelSGDSchedule:
     loss_every: int = 0
     gram: str = "pallas"
     bk: int | None = 512
-    interpret: bool = True
     p_c: int = 1
     delay: int = 0
     bm: int | None = None
@@ -202,7 +200,7 @@ class ParallelSGDSchedule:
 
 def bundle_gram_v(
     indices, values, x, n: int, *, gram: str = "pallas", bk: int | None = 512,
-    bm: int | None = None, precision: str = "fp32", interpret: bool = True,
+    bm: int | None = None, precision: str = "fp32",
 ):
     """The shared s-bundle primitive: local (G, v) = (tril(YYᵀ,-1), Yx)
     for the ELL bundle Y, without densifying Y to (sb, n) in HBM.
@@ -219,8 +217,7 @@ def bundle_gram_v(
     bk = 512 if bk is None else bk
     if gram == "pallas":
         return ell_gram_and_v(
-            indices, values, x, n=n, bk=bk, bm=bm, precision=precision,
-            interpret=interpret,
+            indices, values, x, n=n, bk=bk, bm=bm, precision=precision
         )
     if gram == "blocked":
         return ell_gram_and_v_blocked(
@@ -269,14 +266,16 @@ def inner_corrections(
     exactly the ρ^{s-1-l}-weighted u the caller's Yᵀ apply (and ρ^s·x
     decay-fold) needs. Shared by the engine and the shard_map path (and
     mirrored VMEM-resident by repro.kernels.sstep_inner for the
-    logistic default)."""
+    logistic default). The G·u products run at full f32 precision on
+    every chip, whatever the schedule's precision."""
     lam = objective.l2
 
     if lam == 0.0:
 
         def inner(u_acc, j):
-            zj = jax.lax.dynamic_slice_in_dim(v, j * b, b) + (eta / b) * (
-                jax.lax.dynamic_slice_in_dim(g, j * b, b, axis=0) @ u_acc
+            zj = jax.lax.dynamic_slice_in_dim(v, j * b, b) + (eta / b) * jnp.dot(
+                jax.lax.dynamic_slice_in_dim(g, j * b, b, axis=0), u_acc,
+                precision=jax.lax.Precision.HIGHEST,
             )
             uj = objective.residual(zj)
             return jax.lax.dynamic_update_slice_in_dim(u_acc, uj, j * b, axis=0), None
@@ -288,8 +287,9 @@ def inner_corrections(
 
     def inner_decay(carry, j):
         u_acc, rho_j = carry  # u_acc_l = ρ^{j-1-l}·u_l (l < j); rho_j = ρ^j
-        zj = rho_j * jax.lax.dynamic_slice_in_dim(v, j * b, b) + (eta / b) * (
-            jax.lax.dynamic_slice_in_dim(g, j * b, b, axis=0) @ u_acc
+        zj = rho_j * jax.lax.dynamic_slice_in_dim(v, j * b, b) + (eta / b) * jnp.dot(
+            jax.lax.dynamic_slice_in_dim(g, j * b, b, axis=0), u_acc,
+            precision=jax.lax.Precision.HIGHEST,
         )
         uj = objective.residual(zj)
         u_acc = jax.lax.dynamic_update_slice_in_dim(rho * u_acc, uj, j * b, axis=0)
@@ -303,7 +303,7 @@ def inner_corrections(
 def delayed_bundle_scan(x, *, slice_bundle, bundles: int, n: int,
                         sched: ParallelSGDSchedule, eta,
                         objective: Objective = LOGISTIC,
-                        comm=COUNTING, gram: str | None = None):
+                        comm=COUNTING):
     """The delay-D software pipeline over one round's τ/s bundles —
     the shared round-body core of both backends when ``sched.delay ≥ 1``
     (DaSGD, arXiv:2006.00441).
@@ -328,22 +328,22 @@ def delayed_bundle_scan(x, *, slice_bundle, bundles: int, n: int,
     updates (and, under L2, exactly ``bundles`` decay folds) are
     applied per round, same as the synchronous path.
 
+    At D = 0 the FIFO is empty and each bundle is consumed as it is
+    issued: the synchronous order, through this same code.
+
     ``slice_bundle(t) -> (idx, val)`` supplies the (s·b, width) ELL
     bundle; ``comm`` is COUNTING on the simulated engine (identity —
     the staged value is already globally reduced) and MESH/TIMED under
-    shard_map. ``gram`` overrides the schedule's bundle backend (the
-    shard_map path runs "pallas" as "blocked")."""
+    shard_map."""
     s, b = sched.s, sched.b
     sb = s * b
     d = sched.delay
     lam = objective.l2
-    gram_ = sched.gram if gram is None else gram
 
     def compute_issue(x, t):
         idx, val = slice_bundle(t)
-        g, v = bundle_gram_v(idx, val, x, n, gram=gram_, bk=sched.bk,
-                             bm=sched.bm, precision=sched.precision,
-                             interpret=sched.interpret)
+        g, v = bundle_gram_v(idx, val, x, n, gram=sched.gram, bk=sched.bk,
+                             bm=sched.bm, precision=sched.precision)
         # issued here, consumed D bundles later (the s = 1 corner
         # stages the full (G, v) too — its distributed twin psums the
         # dense block either way, so counted payloads stay pinned).
@@ -385,6 +385,8 @@ def delayed_bundle_scan(x, *, slice_bundle, bundles: int, n: int,
     def body(carry, t):
         x, buf = carry
         new = compute_issue(x, t)
+        if not d:  # the degenerate pipeline: each bundle consumed as issued
+            return (consume_apply(x, new, True), buf), None
         oldest = jax.tree_util.tree_map(lambda a: a[0], buf)
         buf = jax.tree_util.tree_map(
             lambda a, e: jnp.concatenate([a[1:], e[None]], axis=0), buf, new
@@ -400,7 +402,8 @@ def delayed_bundle_scan(x, *, slice_bundle, bundles: int, n: int,
         )
         return consume_apply(x, entry, jnp.bool_(True)), None
 
-    x, _ = jax.lax.scan(drain, x, jnp.arange(d))
+    if d:
+        x, _ = jax.lax.scan(drain, x, jnp.arange(d))
     return x
 
 
@@ -454,8 +457,7 @@ def _team_inner_iterations(indices, values, n: int, x, round_idx, eta,
             u = objective.residual(yx)
         else:
             g, v = bundle_gram_v(idx, val, x, n, gram=sched.gram, bk=sched.bk,
-                                 bm=sched.bm, precision=sched.precision,
-                                 interpret=sched.interpret)
+                                 bm=sched.bm, precision=sched.precision)
             # row-team Allreduce of the bundle (G, v) — identity here
             # (the simulated rank computes the full reduction), the
             # recorded payload when the round body is captured.
@@ -568,6 +570,16 @@ def _engine_chunk(tp, x, r0, eta, sched, k):
 
     x, _ = jax.lax.scan(one_round, x, r0 + jnp.arange(k))
     return x
+
+
+def lower_engine_chunk(tp: TeamProblem, x: jnp.ndarray, k: int,
+                       sched: ParallelSGDSchedule) -> jax.stages.Lowered:
+    """The k-round program ``run_engine_chunk`` dispatches, lowered but
+    not run — to read its compiled HLO or memory analysis."""
+    eta = jnp.asarray(sched.eta, x.dtype)
+    return _engine_chunk.lower(
+        tp, x, jnp.int32(0), eta, _normalize_for_chunk(sched), int(k)
+    )
 
 
 @jax.jit
@@ -694,7 +706,7 @@ def engine_phase_probes(tp: TeamProblem, sched: ParallelSGDSchedule) -> dict:
     compute = jax.jit(
         lambda i, v, x: bundle_gram_v(
             i, v, x, tp.n, gram=sched.gram, bk=sched.bk, bm=sched.bm,
-            precision=sched.precision, interpret=sched.interpret,
+            precision=sched.precision,
         )
     )
     g0 = jnp.zeros((sb, sb), jnp.float32)

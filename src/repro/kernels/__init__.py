@@ -12,7 +12,9 @@ Gram (``gram.py``), the BSR matmul (``bsr_matmul.py``), and their
 ``ops.py`` wrappers were dead paths off the live bundle pipeline and
 have been removed; ``repro.sparse.bsr`` keeps the BSR *layout* (and its
 jnp reference matvec) for the format tests.
-interpret=True on CPU, =False on real TPU.
+Interpret mode follows the platform (``ell_gram.default_interpret``):
+the compiled Mosaic kernels on a TPU ("TPU v5 lite" is the v5e's
+``device_kind``), the Pallas interpreter on every other backend.
 """
 
 from repro.kernels.ell_gram import ell_gram_and_v, ell_gram_and_v_blocked

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -25,9 +26,10 @@ def gram_tril_ref(y) -> jnp.ndarray:
 
 
 def gram_and_v_ref(y, x) -> tuple[jnp.ndarray, jnp.ndarray]:
+    hi = jax.lax.Precision.HIGHEST  # the oracle is exact f32 on every chip
     return (
-        jnp.tril(jnp.dot(y, y.T, preferred_element_type=jnp.float32), k=-1),
-        jnp.dot(y, x, preferred_element_type=jnp.float32),
+        jnp.tril(jnp.dot(y, y.T, precision=hi, preferred_element_type=jnp.float32), k=-1),
+        jnp.dot(y, x, precision=hi, preferred_element_type=jnp.float32),
     )
 
 

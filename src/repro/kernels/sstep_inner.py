@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ell_gram import compute_dtype_for, default_interpret, dot_precision
+
 
 def _inner_kernel(
     g_ref, v_ref, u_ref, *, s: int, b: int, eta_over_b: float, compute_dtype=None
@@ -37,7 +39,8 @@ def _inner_kernel(
             panel = panel.astype(compute_dtype)
             u = u.astype(compute_dtype)
         zj = v_ref[pl.dslice(j * b, b), 0] + eta_over_b * (
-            jnp.dot(panel, u, preferred_element_type=jnp.float32)
+            jnp.dot(panel, u, precision=dot_precision(panel.dtype),
+                    preferred_element_type=jnp.float32)
         )
         uj = jnp.where(zj >= 0, jnp.exp(-zj) / (1 + jnp.exp(-zj)), 1 / (1 + jnp.exp(zj)))
         u_ref[pl.dslice(j * b, b), 0] = uj.astype(u_ref.dtype)
@@ -54,14 +57,13 @@ def sstep_inner(
     eta: float,
     *,
     precision: str = "fp32",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """u (sb,) such that u_j = sigmoid_residual(v_j + (η/b) Σ_{l<j} G_{jl} u_l).
 
     ``precision="bf16"`` runs the G-panel·u MXU dot bf16-in /
-    f32-accumulate; z, the residual, and u stay float32."""
-    from repro.kernels.ell_gram import compute_dtype_for
-
+    f32-accumulate; z, the residual, and u stay float32.
+    ``interpret=None`` takes the platform's mode (``default_interpret``)."""
     cd = compute_dtype_for(precision)
     sb = s * b
     assert g.shape == (sb, sb) and v.shape == (sb,)
@@ -76,7 +78,7 @@ def sstep_inner(
         ],
         out_specs=pl.BlockSpec((sb, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((sb, 1), jnp.float32),
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(g.astype(jnp.float32), v.astype(jnp.float32)[:, None])
     return out[:, 0]
 

@@ -4,11 +4,12 @@ The ELL-Gram kernel has two tiling knobs — the column-panel width
 ``bk`` and the row tile ``bm`` — whose best values depend on the
 dataset's nnz profile (ELL width, local column count) and the device.
 This module sweeps the candidate grid once per (profile, device kind),
-scores candidates by **measured wall time cross-checked against the
-analytic roofline** (``repro.launch.roofline.panel_roofline``: a
-candidate that does not fit VMEM is infeasible; a measurement below the
-attainable bound is a timer glitch and is discarded), and caches the
-winner on disk.
+scores candidates by **measured wall time**, on a TPU cross-checked
+against the analytic roofline of that chip's ``device_kind``
+(``repro.launch.roofline.panel_roofline``: a candidate that does not
+fit VMEM is infeasible; a measurement below the attainable bound is a
+timer glitch and is discarded), and caches the winner on disk. Off-TPU
+there are no chip peaks to apply, so the filter is skipped.
 
 Cache keying mirrors the engine's jit cache: the key is a content hash
 of (profile, device kind, KERNEL_VERSION) — deterministic, so every
@@ -24,8 +25,8 @@ device-free planning) and ``Session`` (the build) must compute the
 identical key without touching data.
 
 Measurement backend: on TPU the compiled Pallas kernel is timed; on CPU
-(this container) Pallas runs in interpret mode, whose per-op Python
-dispatch makes wall time meaningless — the blocked XLA twin
+Pallas runs in interpret mode, whose per-op Python dispatch makes wall
+time meaningless — the blocked XLA twin
 (``ell_gram_and_v_blocked``) is timed instead. It shares the panel
 structure and math (it is what shard_map executes), so the relative
 ranking across (bk, bm) is the quantity the cache stores.
@@ -52,7 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.ell_gram import ell_gram_and_v, ell_gram_and_v_blocked
-from repro.launch.roofline import panel_roofline
+from repro.launch.roofline import ChipPeaks, panel_roofline, peaks_for
 
 __all__ = [
     "KERNEL_VERSION",
@@ -123,7 +124,7 @@ class PanelProfile:
 
 
 def device_kind() -> str:
-    """The cache's device axis, e.g. ``cpu:cpu`` or ``tpu:TPU v5e``."""
+    """The cache's device axis, e.g. ``cpu:cpu`` or ``tpu:TPU v5 lite``."""
     d = jax.devices()[0]
     return f"{d.platform}:{getattr(d, 'device_kind', d.platform)}"
 
@@ -191,13 +192,20 @@ def _synthesize(profile: PanelProfile, max_n: int, seed: int = 0):
     return idx, val, x, n, width
 
 
+def measured_peaks() -> ChipPeaks | None:
+    """Peaks of the device the tuner times on: its ``device_kind``'s
+    entry on a TPU (unknown kinds raise), None on any other platform."""
+    d = jax.devices()[0]
+    return peaks_for(d.device_kind) if d.platform == "tpu" else None
+
+
 def _time_candidate(idx, val, x, n, bk, bm, precision, repeats: int) -> float:
     """Median wall seconds of one jitted (G, v) bundle build."""
     on_tpu = jax.devices()[0].platform == "tpu"
     if on_tpu:
         fn = jax.jit(
             lambda i, v, z: ell_gram_and_v(
-                i, v, z, n=n, bk=bk, bm=bm, precision=precision, interpret=False
+                i, v, z, n=n, bk=bk, bm=bm, precision=precision
             )
         )
     else:
@@ -237,8 +245,9 @@ def tune_panel(
         candidates                             — the full audited table
 
     Candidate filtering: bk capped at the measured extent, bm capped at
-    rows, VMEM-infeasible shapes dropped, and any measurement *below*
-    its roofline bound discarded as a timer glitch (the cross-check).
+    rows; on a TPU also VMEM-infeasible shapes dropped and any
+    measurement *below* its roofline bound discarded as a timer glitch
+    (the cross-check). Off-TPU the roofline fields stay None.
     """
     device = device_kind() if device is None else device
     key = cache_key(profile, device)
@@ -252,10 +261,16 @@ def tune_panel(
     bks = sorted({min(bk, -(-n // 8) * 8) for bk in bk_candidates})
     bms = sorted({bm for bm in bm_candidates if bm is None or bm < rows},
                  key=lambda v: -1 if v is None else v)
+    peaks = measured_peaks()
     table = []
     for bk in bks:
         for bm in bms:
-            rl = panel_roofline(rows, width, n, bk, bm, profile.precision)
+            if peaks is None:
+                t = _time_candidate(idx, val, x, n, bk, bm, profile.precision, repeats)
+                table.append({"bk": bk, "bm": bm, "measured_s": t,
+                              "attainable_s": None, "skipped": None})
+                continue
+            rl = panel_roofline(rows, width, n, bk, bm, profile.precision, peaks=peaks)
             if not rl.fits_vmem:
                 table.append({"bk": bk, "bm": bm, "skipped": "vmem",
                               "vmem_bytes": rl.vmem_bytes})
@@ -286,7 +301,10 @@ def tune_panel(
         "bm": best["bm"],
         "measured_s": best["measured_s"],
         "attainable_s": best["attainable_s"],
-        "efficiency": best["attainable_s"] / best["measured_s"],
+        "efficiency": (
+            None if best["attainable_s"] is None
+            else best["attainable_s"] / best["measured_s"]
+        ),
         "candidates": table,
     }
     store_record(record, cache_dir)

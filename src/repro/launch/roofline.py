@@ -24,11 +24,48 @@ from __future__ import annotations
 import dataclasses
 import re
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12  # bf16
-HBM_BW = 819e9  # B/s
-ICI_BW = 50e9  # B/s per link (effective, see DESIGN.md)
-VMEM_BYTES = 16 * 2**20  # per-core VMEM budget the panel tiler fits in
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator ``device_kind``."""
+
+    bf16_flops: float  # FLOP/s
+    hbm_bw: float  # B/s
+    hbm_bytes: int
+    vmem_bytes: int  # scoped-VMEM budget the panel tiler fits in
+    source: str
+
+
+# Keyed by ``jax.Device.device_kind``: a TPU v5e reports "TPU v5 lite".
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12,
+        hbm_bw=819e9,
+        hbm_bytes=16 * 2**30,
+        vmem_bytes=16 * 2**20,
+        source="Google Cloud, TPU v5e",
+    ),
+}
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; a chip missing from ``PEAKS`` is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind={device_kind!r} "
+            f"(known: {sorted(PEAKS)})"
+        ) from None
+
+
+# the dry-run roofline below prices the v5e production mesh
+_V5E = PEAKS["TPU v5 lite"]
+PEAK_FLOPS = _V5E.bf16_flops
+HBM_BW = _V5E.hbm_bw
+ICI_BW = 50e9  # B/s per link (effective)
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -237,8 +274,9 @@ class PanelRoofline:
     flops: float
     hbm_bytes: float
     vmem_bytes: int
-    peak_flops: float = PEAK_FLOPS
-    hbm_bw: float = HBM_BW
+    peak_flops: float
+    hbm_bw: float
+    vmem_limit: int
 
     @property
     def compute_s(self) -> float:
@@ -259,7 +297,7 @@ class PanelRoofline:
 
     @property
     def fits_vmem(self) -> bool:
-        return self.vmem_bytes <= VMEM_BYTES
+        return self.vmem_bytes <= self.vmem_limit
 
 
 def panel_roofline(
@@ -269,14 +307,17 @@ def panel_roofline(
     bk: int,
     bm: int | None = None,
     precision: str = "fp32",
+    *,
+    peaks: ChipPeaks,
 ) -> PanelRoofline:
-    """The attainable-FLOP/s justification for one tuner candidate.
+    """The attainable-FLOP/s justification for one tuner candidate on
+    the chip whose ``peaks`` are given (``peaks_for(device_kind)``).
 
-    ``precision`` prices the MXU: bf16 panels run at the full PEAK_FLOPS
-    (the constant is the bf16 peak) with 2-byte panel tiles; fp32 halves
-    the peak and doubles the tile."""
+    ``precision`` prices the MXU: bf16 panels run at the full bf16 peak
+    with 2-byte panel tiles; fp32 halves the peak and doubles the
+    tile."""
     cb = 2 if precision == "bf16" else 4
-    peak = PEAK_FLOPS if precision == "bf16" else PEAK_FLOPS / 2
+    peak = peaks.bf16_flops if precision == "bf16" else peaks.bf16_flops / 2
     return PanelRoofline(
         rows=rows,
         width=width,
@@ -287,6 +328,8 @@ def panel_roofline(
         hbm_bytes=panel_hbm_bytes(rows, width, n, bk, cb),
         vmem_bytes=panel_vmem_bytes(rows, width, bk, bm, cb),
         peak_flops=peak,
+        hbm_bw=peaks.hbm_bw,
+        vmem_limit=peaks.vmem_bytes,
     )
 
 

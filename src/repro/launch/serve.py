@@ -29,6 +29,7 @@ from pathlib import Path
 import numpy as np
 
 from repro.api import ExperimentSpec, Session
+from repro.launch.cache import place_compile_cache
 from repro.obs import export as obs_export
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -70,6 +71,7 @@ def main(argv=None) -> int:
                          "and write a Chrome trace-event JSON here (loads in "
                          "Perfetto; a .jsonl event log lands beside it)")
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     spec = ExperimentSpec.from_json(Path(args.spec).read_text())
     if not spec.stream.enabled:

@@ -25,7 +25,7 @@ and re-invoke with the same ``--resume`` to continue — finished points
 are rehydrated, never re-run. A point that keeps failing is retried per
 its spec's ``FaultPolicy`` and then quarantined (``[quar ]`` line; the
 record lands in the ``--out`` dump) while the rest of the sweep
-completes. ``--table`` prints the paper-style time-to-loss table (§7.5)
+completes; the command then exits non-zero. ``--table`` prints the paper-style time-to-loss table (§7.5)
 over the collected reports.
 
 The communication loop closes here too: ``--timed`` runs every spec
@@ -52,6 +52,7 @@ from pathlib import Path
 
 from repro.api import ExperimentSpec, RunReport, calibrate, plan, sweep
 from repro.core.objective import OBJECTIVES
+from repro.launch.cache import place_compile_cache
 from repro.obs import export as obs_export
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -154,6 +155,7 @@ def main(argv: list[str] | None = None) -> None:
         ap.error("--calibrate requires --plan-only")
     if args.trace is not None and args.plan_only:
         ap.error("--trace records a run — drop --plan-only")
+    place_compile_cache()
 
     specs = load_specs(args.spec)
     override = {}
@@ -227,6 +229,11 @@ def main(argv: list[str] | None = None) -> None:
     # the full SweepReport dict (reports + quarantine records) is the
     # artifact CI uploads; _report_dicts/--calibrate accept this shape.
     _finish(args, result.to_dict(), result.summary())
+    if result.quarantined:
+        raise SystemExit(
+            f"{len(result.quarantined)} sweep point(s) quarantined — see the "
+            f"[quar ] lines"
+        )
 
 
 def _print_reranked(planned, preset) -> None:

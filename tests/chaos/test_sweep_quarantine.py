@@ -165,3 +165,26 @@ def test_keyboard_interrupt_is_not_retried(tmp_path):
     finally:
         Session.__init__ = real_init
     assert calls["n"] == 1
+
+
+def test_sweep_cli_exits_nonzero_on_quarantine(tmp_path, capsys, monkeypatch):
+    """``repro.launch.sweep`` finishes the sweep, prints the ``[quar ]``
+    line, and then exits non-zero: a quarantined point is a failure."""
+    from repro.launch.sweep import main
+
+    # with the variable set, main() leaves the worker's compile cache alone
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax_cache"))
+    path = tmp_path / "sweep.json"
+    path.write_text(json.dumps([
+        _spec("doomed", faults=FaultPolicy(max_retries=0)).to_dict(),
+        _spec("fine").to_dict(),
+    ]))
+    plan = FaultPlan(
+        events=[FaultEvent(kind="io_error", site="point", at=0, times=99)]
+    )
+    with install(plan), pytest.raises(SystemExit) as ei:
+        main(["--spec", str(path)])
+    assert ei.value.code not in (0, None)
+    out = capsys.readouterr().out
+    assert "[quar ] doomed" in out and "[run  ] fine" in out
+    main(["--spec", str(path)])  # no fault: the same sweep returns normally

@@ -9,11 +9,16 @@ device can host — the full dispatch path, no fake devices needed.
 
 import dataclasses
 import json
+import os
+import subprocess
+import sys
 import warnings
+from pathlib import Path
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.api import ExperimentSpec, MeshSpec, StopPolicy, build_problem, plan, run
@@ -285,3 +290,56 @@ def test_make_hybrid_step_legacy_scalars_warn(tiny_2d):
         with warnings.catch_warnings():
             warnings.simplefilter("ignore")
             make_hybrid_step(mesh, prob)  # neither schedule nor scalars
+
+
+def test_interpret_is_not_a_schedule_or_spec_field():
+    """Pallas interpret mode follows the platform; no schedule field,
+    spec key or committed spec file can select it."""
+    assert "interpret" not in {f.name for f in dataclasses.fields(ParallelSGDSchedule)}
+    with pytest.raises(TypeError):
+        ParallelSGDSchedule(interpret=True)
+    d = hybrid_spec().to_dict()
+    assert "interpret" not in d["schedule"]
+    d["schedule"]["interpret"] = False
+    with pytest.raises(TypeError):
+        ExperimentSpec.from_dict(d)
+    specs = sorted((Path(__file__).parent.parent / "examples" / "specs").glob("*.json"))
+    assert specs
+    for path in specs:
+        assert "interpret" not in path.read_text(), path
+        raw = json.loads(path.read_text())
+        for entry in raw if isinstance(raw, list) else [raw]:
+            ExperimentSpec.from_dict(entry)
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """Unset, the cache goes to the fixed ``<checkout>/.jax_cache``; with
+    JAX_COMPILATION_CACHE_DIR set, nothing in the code sets another and
+    the compiled program lands there."""
+    from repro.launch import cache
+
+    checkout = Path(__file__).resolve().parent.parent
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        assert cache.place_compile_cache() == checkout / ".jax_cache"
+        assert jax.config.jax_compilation_cache_dir == str(checkout / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", was)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+        assert cache.place_compile_cache() == tmp_path / "env"
+        assert jax.config.jax_compilation_cache_dir == was  # left to JAX
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "env"),
+               PYTHONPATH=str(checkout / "src"), JAX_PLATFORMS="cpu")
+    code = (
+        "import jax; from repro.launch.cache import place_compile_cache; "
+        "place_compile_cache(); "
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0); "
+        "print(jax.jit(lambda a: a * 3 + 1)(2.0))"
+    )
+    out = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True,
+                         text=True, timeout=300)
+    assert out.returncode == 0, out.stderr
+    assert any((tmp_path / "env").iterdir())

@@ -114,3 +114,56 @@ def test_sstep_inner_kernel_in_solver_context():
     # oracle: s plain SGD steps
     x_ref, _ = run_sgd(prob, x, b, eta, s)
     np.testing.assert_allclose(np.asarray(x_new), np.asarray(x_ref), rtol=1e-4, atol=1e-4)
+
+
+def _interpret_flags(fn, *args):
+    """The ``interpret`` mode of every pallas_call ``fn`` traces to."""
+    import jax
+
+    eqns = jax.make_jaxpr(fn)(*args).jaxpr.eqns
+    return [e.params["interpret"] for e in eqns if e.primitive.name == "pallas_call"]
+
+
+def test_kernels_take_interpret_mode_from_the_platform(monkeypatch):
+    """No option selects interpret mode: the kernels interpret off-TPU
+    and compile on a TPU; only an explicit ``interpret=`` overrides."""
+    import jax
+
+    from repro.kernels.sstep_inner import sstep_inner
+
+    idx, val, x = jnp.zeros((8, 4), jnp.int32), jnp.ones((8, 4)), jnp.ones(64)
+    g, v = jnp.zeros((8, 8)), jnp.zeros(8)
+
+    def gram(**kw):
+        return _interpret_flags(
+            lambda i, w, z: ell_gram_and_v(i, w, z, n=64, bk=32, **kw), idx, val, x
+        )
+
+    def inner():
+        return _interpret_flags(lambda a, b: sstep_inner(a, b, 2, 4, 0.1), g, v)
+
+    assert jax.default_backend() == "cpu"
+    assert gram() == [True] and inner() == [True]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert gram() == [False] and inner() == [False]
+    assert gram(interpret=True) == [True]
+
+
+@pytest.mark.parametrize("split_f32", [False, True], ids=["f32", "bf16-terms"])
+def test_f32_panel_is_exact(split_f32):
+    """Both f32 panel builds place every ELL value bit for bit: the plain
+    contraction, and the three bf16 terms the compiled kernel sums
+    (values over many binades, with all 24 significand bits in use)."""
+    import jax
+
+    from repro.kernels.ell_gram import _panel_rows
+
+    rng = np.random.default_rng(5)
+    rows, w, bk = 16, 24, 128
+    idx = np.stack([rng.choice(bk, w, replace=False) for _ in range(rows)]).astype(np.int32)
+    val = (rng.standard_normal((rows, w)) * 10.0 ** rng.integers(-6, 6, (rows, w)))
+    val = val.astype(np.float32)
+    panel = jax.jit(lambda i, v: _panel_rows(i, v, 0, bk, jnp.float32, split_f32))(idx, val)
+    want = np.zeros((rows, bk), np.float32)
+    np.put_along_axis(want, idx, val, axis=1)
+    np.testing.assert_array_equal(np.asarray(panel), want)

