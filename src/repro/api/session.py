@@ -44,7 +44,7 @@ from repro.api.plan import Plan, plan, replan_mesh
 from repro.api.report import RunReport, modeled_comm_words
 from repro.api.spec import ExperimentSpec, MeshSpec
 from repro.core import faults
-from repro.core.comm import MESH, TIMED, CommLedger, time_dispatch, time_phase
+from repro.core.comm import MESH, TIMED, CommLedger, time_phase
 from repro.core.engine import (
     engine_comm_ledger,
     engine_loss,
@@ -264,19 +264,13 @@ class Session:
             jax.block_until_ready(self._x)
 
     def _traced_advance(self, sub: int, first: bool, stream_batch=None) -> None:
-        """One sub-chunk through the tracing seam: untraced, exactly the
-        bare advance (the bitwise-identical default path); traced, the
-        same advance wrapped in a host-side span, blocking at the span
-        edge so the recorded wall covers the dispatched work (observer
-        effect on timing only — the compiled numerics are untouched)."""
-        rec = obs_trace.active()
-        if rec is None:
-            if stream_batch is not None:
-                self._advance_stream(stream_batch)
-            else:
-                self._advance(sub)
-            return
-        with rec.span(
+        """One sub-chunk through the tracing seam: a ``round`` (the
+        first chunk: ``compile``) span around exactly the bare advance.
+        In a profile its length is the host's dispatch time. With a
+        recorder installed the span also blocks at its edge, so the
+        recorded wall covers the dispatched work (observer effect on
+        timing only — the compiled numerics are untouched)."""
+        with obs_trace.span(
             "compile" if first else "round",
             name=f"rounds[{self.rounds_done}+{sub}]",
             start_round=self.rounds_done,
@@ -286,54 +280,37 @@ class Session:
                 self._advance_stream(stream_batch)
             else:
                 self._advance(sub)
-            self._sync()
+            if obs_trace.active() is not None:
+                self._sync()
 
     def _measure_phases(self) -> None:
         """Populate ``ledger.phase_seconds`` (→ ``exposed_comm_s``) once
         per timed run: the §6.5 phase split, measured by separate jitted
         probes over the round's real payload shapes — the training step
         itself is never split or re-traced. Runs outside the wall/compile
-        accounting windows; each probed phase also lands as a trace span
-        when a recorder is installed."""
+        accounting windows."""
         from repro.core.engine import engine_phase_probes
 
         if self._driver is not None:
             probes = self._driver.phase_probes()
         else:
             probes = engine_phase_probes(self.bundle.team, self.spec.schedule)
-        rec = obs_trace.active()
-        delay = self.spec.schedule.delay
-        phases = {}
-        for name, (fn, args, calls) in probes.items():
-            per_call = time_phase(fn, *args)
-            phases[name] = per_call * calls
-            if rec is None:
-                continue
-            if name == "allreduce_gv" and delay >= 1:
-                # delay-D split: the issue half is the async dispatch
-                # cost (measured — what the critical path pays while
-                # the reduction is in flight); the await half is the
-                # exposed remainder after D bundle-computes of overlap
-                # (the ledger's closed form, so trace and ledger agree).
-                issue_call = time_dispatch(fn, *args)
-                issue = min(issue_call, per_call) * calls
-                compute = phases.get("bundle_compute", 0.0)
-                await_s = max(phases[name] - issue - delay * compute, 0.0)
-                rec.add_span("allreduce_gv_issue", f"probe:{name}:issue",
-                             dur=issue, per_call_s=issue_call,
-                             calls_per_round=calls)
-                rec.add_span("allreduce_gv_await", f"probe:{name}:await",
-                             dur=await_s, delay=delay,
-                             calls_per_round=calls)
-            else:
-                rec.add_span(name, f"probe:{name}", dur=phases[name],
-                             per_call_s=per_call, calls_per_round=calls)
-        self.ledger.set_phase_seconds(phases)
+        self.ledger.set_phase_seconds({
+            name: time_phase(fn, *args) * calls
+            for name, (fn, args, calls) in probes.items()
+        })
 
     def _sample_loss(self) -> float:
-        if self._driver is not None:
-            return self._driver.loss()
-        return float(engine_loss(self._gp, self._x))
+        with obs_trace.span("probe"):
+            if self._driver is not None:
+                return self._driver.loss()
+            return float(engine_loss(self._gp, self._x))
+
+    def _copy_x(self) -> np.ndarray:
+        """``current_x()`` through the tracing seam: the host copy of the
+        weights a ``RoundEvent`` or a checkpoint carries."""
+        with obs_trace.span("copy_x", bytes=4 * self.bundle.dataset.A.n):
+            return self.current_x()
 
     def step_rounds(self, k: int | None = None) -> RoundEvent:
         """Advance up to ``k`` rounds (default: to the next loss-sampling
@@ -347,6 +324,10 @@ class Session:
         evaluated at every boundary, so a step spanning several may end
         early (``RoundEvent.stop`` says why).
         """
+        with obs_trace.span("step", step_num=self.rounds_done):
+            return self._step_rounds(k)
+
+    def _step_rounds(self, k: int | None) -> RoundEvent:
         if self.done:
             raise RuntimeError(
                 f"session is finished ({self.stop_reason or 'rounds'} at round "
@@ -424,7 +405,7 @@ class Session:
 
         return RoundEvent(
             rounds_done=self.rounds_done,
-            x=self.current_x(),  # post-sync: a copy, not a timed stall
+            x=self._copy_x(),  # post-sync: a copy, not a timed stall
             loss=loss,
             wall_time_s=self.wall_time_s,
             compile_time_s=self.compile_time_s,
@@ -513,6 +494,10 @@ class Session:
         — sources replay deterministically, so resume continues the
         identical sequence with no duplicated or dropped batch.
         """
+        with obs_trace.span("step", step_num=self.rounds_done):
+            return self._step_stream(source, k)
+
+    def _step_stream(self, source, k: int | None) -> RoundEvent:
         from repro.serve.stream import StreamDesyncError
 
         if self.done:
@@ -586,7 +571,7 @@ class Session:
 
         return RoundEvent(
             rounds_done=self.rounds_done,
-            x=self.current_x(),  # post-sync: a copy, not a timed stall
+            x=self._copy_x(),  # post-sync: a copy, not a timed stall
             loss=loss,
             wall_time_s=self.wall_time_s,
             compile_time_s=self.compile_time_s,
@@ -662,7 +647,7 @@ class Session:
             spec_dict=self.input_spec.to_dict(),
             spec_hash=self.input_spec.content_hash(),
             rounds_done=self.rounds_done,
-            x=self.current_x(),
+            x=self._copy_x(),
             losses=np.asarray(self.losses, np.float32),
             wall_time_s=self.wall_time_s,
             compile_time_s=self.compile_time_s,

@@ -68,7 +68,6 @@ __all__ = [
     "CommLedger",
     "CommRate",
     "capture_rates",
-    "time_dispatch",
     "time_phase",
 ]
 
@@ -528,25 +527,4 @@ def time_phase(fn, *args, repeats: int = 5) -> float:
         t0 = _time.perf_counter()
         jax.block_until_ready(fn(*args))
         walls.append(_time.perf_counter() - t0)
-    return statistics.median(walls)
-
-
-def time_dispatch(fn, *args, repeats: int = 5) -> float:
-    """Median wall seconds to *dispatch* one call of a compiled probe —
-    the host returns as soon as the async runtime has enqueued the work,
-    without blocking on the value. This is what an issued collective
-    costs the critical path while its transfer is in flight; the
-    complement ``time_phase − time_dispatch`` is the hideable window.
-    Each repeat still drains the device afterwards (outside the timed
-    region) so queued work from one repeat never backs up into the
-    next."""
-    import time as _time
-
-    jax.block_until_ready(fn(*args))  # warmup / compile
-    walls = []
-    for _ in range(int(repeats)):
-        t0 = _time.perf_counter()
-        out = fn(*args)
-        walls.append(_time.perf_counter() - t0)
-        jax.block_until_ready(out)
     return statistics.median(walls)

@@ -49,12 +49,13 @@ from repro.core.engine import (
     bundle_gram_v,
     check_delay,
     delayed_bundle_scan,
+    engine_loss,
     inner_corrections,
     unwire_gv,
     wire_gv,
 )
 from repro.core.objective import LOGISTIC, Objective, get_objective
-from repro.core.problem import Problem, problem_loss
+from repro.core.problem import Problem
 from repro.sparse.csr import CSRMatrix
 from repro.sparse.ell import EllBlock, ell_rmatvec
 from repro.sparse.partition import ColumnPartition, partition_columns, partition_rows
@@ -235,7 +236,8 @@ def _build_round_fn(prob: Hybrid2DProblem, sched: ParallelSGDSchedule,
                 x_loc, slice_bundle=slice_bundle, bundles=bundles, n=n_loc,
                 sched=sched, eta=eta_, objective=objective, comm=comm,
             )
-            return comm.allmean_rows(x_loc)
+            with jax.named_scope("param_avg"):
+                return comm.allmean_rows(x_loc)
 
         def bundle(x_loc, t):
             k0 = round_idx * bundles + t
@@ -246,34 +248,39 @@ def _build_round_fn(prob: Hybrid2DProblem, sched: ParallelSGDSchedule,
             # then the row-team Allreduce (paper Table 3 payload; bf16
             # words under the precision knob — the psum sums narrow
             # payloads, corrections run on the f32 upcast)
-            g_part, v_part = bundle_gram_v(
-                bi, bv, x_loc, n_loc, gram=sched.gram, bk=sched.bk, bm=sched.bm,
-                precision=sched.precision,
-            )
-            g, v = comm.allreduce_cols(
-                wire_gv((g_part, v_part), sched.precision),
-                calls_per_round=bundles,
-            )
-            g, v = unwire_gv((g, v), sched.precision)
-            u = inner_corrections(g, v, s, b_, eta_, objective)
+            with jax.named_scope("bundle_gram"):
+                g_part, v_part = bundle_gram_v(
+                    bi, bv, x_loc, n_loc, gram=sched.gram, bk=sched.bk, bm=sched.bm,
+                    precision=sched.precision,
+                )
+            with jax.named_scope("allreduce_gv"):
+                g, v = comm.allreduce_cols(
+                    wire_gv((g_part, v_part), sched.precision),
+                    calls_per_round=bundles,
+                )
+                g, v = unwire_gv((g, v), sched.precision)
+            with jax.named_scope("corrections"):
+                u = inner_corrections(g, v, s, b_, eta_, objective)
             # Yᵀu stays local under column partitioning
             blk = EllBlock(indices=bi, values=bv, n=n_loc)
-            if lam == 0.0:
-                return x_loc + (eta_ / b_) * ell_rmatvec(blk, u).astype(x_loc.dtype), None
-            # decay-folded update, exact under column sharding: the
-            # L2 decay is elementwise, so each shard decays its own
-            # slice (padded slots stay zero: ρ·0 + 0).
-            rho_s = jnp.asarray(1.0 - eta_ * lam, x_loc.dtype) ** s
-            return (
-                rho_s * x_loc + (eta_ / b_) * ell_rmatvec(blk, u).astype(x_loc.dtype),
-                None,
-            )
+            with jax.named_scope("update"):
+                if lam == 0.0:
+                    return x_loc + (eta_ / b_) * ell_rmatvec(blk, u).astype(x_loc.dtype), None
+                # decay-folded update, exact under column sharding: the
+                # L2 decay is elementwise, so each shard decays its own
+                # slice (padded slots stay zero: ρ·0 + 0).
+                rho_s = jnp.asarray(1.0 - eta_ * lam, x_loc.dtype) ** s
+                return (
+                    rho_s * x_loc + (eta_ / b_) * ell_rmatvec(blk, u).astype(x_loc.dtype),
+                    None,
+                )
 
         x_loc, _ = jax.lax.scan(bundle, x_loc, jnp.arange(bundles))
         # column Allreduce: FedAvg averaging across row teams (n/p_c
         # words) — the result is row-replicated, so the out_spec can
         # drop the "rows" axis.
-        return comm.allmean_rows(x_loc)
+        with jax.named_scope("param_avg"):
+            return comm.allmean_rows(x_loc)
 
     return round_fn
 
@@ -539,7 +546,7 @@ class HybridDriver:
         at the current iterate."""
         if self.loss_problem is None:
             raise ValueError("HybridDriver was built without loss_problem")
-        return float(problem_loss(self.loss_problem, jnp.asarray(self.gather())))
+        return float(engine_loss(self.loss_problem, jnp.asarray(self.gather())))
 
 
 def run_hybrid_distributed(

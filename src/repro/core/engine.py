@@ -38,6 +38,13 @@ identity on this backend's already-global values), so
 ``engine_comm_ledger`` can capture exactly what a run communicates and
 reports can place it next to the Eq. 4 model's predictions.
 
+The round body names its device work with ``jax.named_scope`` —
+``bundle_gram``, ``allreduce_gv``, ``corrections``, ``update``,
+``param_avg``, and ``loss_probe`` in ``engine_loss`` — the same names
+as ``repro.core.distributed``'s, so a profile attributes each op to its
+phase on either backend. Scopes are metadata only: the iterates are
+unchanged.
+
 repro.core.{sgd,sstep,fedavg,hybrid} re-export configured engine calls
 for backwards compatibility.
 """
@@ -342,29 +349,34 @@ def delayed_bundle_scan(x, *, slice_bundle, bundles: int, n: int,
 
     def compute_issue(x, t):
         idx, val = slice_bundle(t)
-        g, v = bundle_gram_v(idx, val, x, n, gram=sched.gram, bk=sched.bk,
-                             bm=sched.bm, precision=sched.precision)
+        with jax.named_scope("bundle_gram"):
+            g, v = bundle_gram_v(idx, val, x, n, gram=sched.gram, bk=sched.bk,
+                                 bm=sched.bm, precision=sched.precision)
         # issued here, consumed D bundles later (the s = 1 corner
         # stages the full (G, v) too — its distributed twin psums the
         # dense block either way, so counted payloads stay pinned).
         # Under bf16 the staged payload is the wire dtype: the FIFO
         # holds exactly what the in-flight Allreduce carries.
-        g, v = comm.issue_allreduce_cols(
-            wire_gv((g, v), sched.precision), calls_per_round=bundles
-        )
+        with jax.named_scope("allreduce_gv"):
+            g, v = comm.issue_allreduce_cols(
+                wire_gv((g, v), sched.precision), calls_per_round=bundles
+            )
         return idx, val, g, v
 
     def consume_apply(x, entry, live):
         idx, val, g, v = entry
-        g, v = comm.await_allreduce((g, v))
-        g, v = unwire_gv((g, v), sched.precision)
-        u = inner_corrections(g, v, s, b, eta, objective)
-        blk = EllBlock(indices=idx, values=val, n=n)
-        upd = (eta / b) * ell_rmatvec(blk, u).astype(x.dtype)
-        if lam == 0.0:
-            return jnp.where(live, x + upd, x)
-        rho_s = jnp.asarray(1.0 - eta * lam, x.dtype) ** s
-        return jnp.where(live, rho_s * x + upd, x)
+        with jax.named_scope("allreduce_gv"):
+            g, v = comm.await_allreduce((g, v))
+            g, v = unwire_gv((g, v), sched.precision)
+        with jax.named_scope("corrections"):
+            u = inner_corrections(g, v, s, b, eta, objective)
+        with jax.named_scope("update"):
+            blk = EllBlock(indices=idx, values=val, n=n)
+            upd = (eta / b) * ell_rmatvec(blk, u).astype(x.dtype)
+            if lam == 0.0:
+                return jnp.where(live, x + upd, x)
+            rho_s = jnp.asarray(1.0 - eta * lam, x.dtype) ** s
+            return jnp.where(live, rho_s * x + upd, x)
 
     # the D-deep staging FIFO: buf[0] is the oldest in-flight bundle.
     # Shapes/dtypes are written out by hand (an eval_shape through
@@ -448,31 +460,39 @@ def _team_inner_iterations(indices, values, n: int, x, round_idx, eta,
             # full (G, v) bundle even at s = 1 (G rides the wire though
             # numerically unused), so the counted payload is pinned to
             # the same sb² + sb words.
-            yx = COUNTING.allreduce_cols(
-                wire_gv(ell_matvec(bundle, x), sched.precision),
-                calls_per_round=bundles,
-                words_per_call=sb * sb + sb,
-            )
-            yx = unwire_gv(yx, sched.precision, x.dtype)
-            u = objective.residual(yx)
+            with jax.named_scope("bundle_gram"):
+                yx = ell_matvec(bundle, x)
+            with jax.named_scope("allreduce_gv"):
+                yx = COUNTING.allreduce_cols(
+                    wire_gv(yx, sched.precision),
+                    calls_per_round=bundles,
+                    words_per_call=sb * sb + sb,
+                )
+                yx = unwire_gv(yx, sched.precision, x.dtype)
+            with jax.named_scope("corrections"):
+                u = objective.residual(yx)
         else:
-            g, v = bundle_gram_v(idx, val, x, n, gram=sched.gram, bk=sched.bk,
-                                 bm=sched.bm, precision=sched.precision)
+            with jax.named_scope("bundle_gram"):
+                g, v = bundle_gram_v(idx, val, x, n, gram=sched.gram, bk=sched.bk,
+                                     bm=sched.bm, precision=sched.precision)
             # row-team Allreduce of the bundle (G, v) — identity here
             # (the simulated rank computes the full reduction), the
             # recorded payload when the round body is captured.
-            g, v = COUNTING.allreduce_cols(
-                wire_gv((g, v), sched.precision), calls_per_round=bundles
-            )
-            g, v = unwire_gv((g, v), sched.precision)
-            u = inner_corrections(g, v, s, b, eta, objective)
-        if lam == 0.0:
-            return x + (eta / b) * ell_rmatvec(bundle, u).astype(x.dtype), None
-        # decay-folded update: x_s = ρ^s·x + (η/b)·Yᵀ·[ρ^{s-1-l}·u_l]
-        # (inner_corrections already returns the ρ-weighted u; for
-        # s = 1 the weight is ρ^0 = 1). Exact on the s = 1 corners.
-        rho_s = jnp.asarray(1.0 - eta * lam, x.dtype) ** s
-        return rho_s * x + (eta / b) * ell_rmatvec(bundle, u).astype(x.dtype), None
+            with jax.named_scope("allreduce_gv"):
+                g, v = COUNTING.allreduce_cols(
+                    wire_gv((g, v), sched.precision), calls_per_round=bundles
+                )
+                g, v = unwire_gv((g, v), sched.precision)
+            with jax.named_scope("corrections"):
+                u = inner_corrections(g, v, s, b, eta, objective)
+        with jax.named_scope("update"):
+            if lam == 0.0:
+                return x + (eta / b) * ell_rmatvec(bundle, u).astype(x.dtype), None
+            # decay-folded update: x_s = ρ^s·x + (η/b)·Yᵀ·[ρ^{s-1-l}·u_l]
+            # (inner_corrections already returns the ρ-weighted u; for
+            # s = 1 the weight is ρ^0 = 1). Exact on the s = 1 corners.
+            rho_s = jnp.asarray(1.0 - eta * lam, x.dtype) ** s
+            return rho_s * x + (eta / b) * ell_rmatvec(bundle, u).astype(x.dtype), None
 
     x, _ = jax.lax.scan(bundle_step, x, jnp.arange(bundles))
     return x
@@ -502,7 +522,8 @@ def _one_round(tp, x, r, eta, sched):
     # column Allreduce: the p_r-team average, issued through the comm
     # plane (numerically the same stacked mean; the per-rank payload is
     # the balanced ⌈n/p_c⌉-word weight shard — Table 3's sync column).
-    return COUNTING.allmean_teams(xs, words_per_call=-(-tp.n // sched.p_c))
+    with jax.named_scope("param_avg"):
+        return COUNTING.allmean_teams(xs, words_per_call=-(-tp.n // sched.p_c))
 
 
 @partial(jax.jit, static_argnames=("sched",))
@@ -586,7 +607,8 @@ def lower_engine_chunk(tp: TeamProblem, x: jnp.ndarray, k: int,
 def engine_loss(gp, x):
     """The session's loss probe — same ``problem_loss`` (under ``gp``'s
     objective) the monolithic scan samples at chunk boundaries."""
-    return problem_loss(gp, x)
+    with jax.named_scope("loss_probe"):
+        return problem_loss(gp, x)
 
 
 def run_engine_chunk(

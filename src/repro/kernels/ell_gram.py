@@ -242,6 +242,7 @@ def ell_gram_and_v(
             jax.ShapeDtypeStruct((sb, 1), acc),
         ],
         interpret=interpret,
+        name="ell_gram",
     )(indices, values.astype(acc), x[:, None])
     return g, v[:, 0]
 

@@ -1,7 +1,9 @@
 """repro.obs — the observability plane.
 
-``trace``    Span/TraceRecorder seam (contextmanager + ContextVar,
-             inert when uninstalled) with the closed span-category set.
+``trace``    the span seam: ``repro.<category>`` annotations in any
+             ``jax.profiler`` trace, plus a ``TraceRecorder`` when one
+             is installed (contextmanager + ContextVar); the closed
+             span-category set.
 ``metrics``  process-wide registry of typed Counter/Gauge/Histogram
              instruments with labeled snapshots and deltas.
 ``export``   Chrome trace-event JSON (Perfetto-loadable) and JSONL

@@ -1,41 +1,38 @@
 """Structured span tracing — the repo's observability seam.
 
-The paper's empirical claims rest on a *time breakdown*: computation
-vs. the two communication phases of Eq. 4, calibrated per machine in
-§6.5. Before this module the repo could only measure whole rounds
-(``CommLedger.round_seconds``) and whole runs (``RunReport``'s
-compile/solve walls) — nothing could attribute wall time to a phase
-*inside* a round, which is exactly what the overlap/asynchrony work
-(exposed vs. total comm time) needs.
+This is the tracing half of ``repro.obs``. Instrumented sites across
+train/sweep/serve call the module-level ``span(category, name, **args)``
+around a layer boundary, and every span goes to up to two sinks:
 
-This is the tracing half of ``repro.obs``: a ``TraceRecorder`` collects
-``Span``s — named, categorized, nested wall-clock intervals — from
-instrumented sites across train/sweep/serve. The seam follows
-``repro.core.faults`` exactly:
+* the JAX profiler, always: the span opens a
+  ``jax.profiler.TraceAnnotation`` named ``repro.<category>`` with
+  ``name`` and ``args`` as its stats (``step`` opens a
+  ``StepTraceAnnotation``, so xprof's step view splits on it). Any
+  ``jax.profiler`` trace taken around the program therefore holds the
+  Session's layer boundaries on the device trace's own clock. With no
+  profiler running an annotation costs one small object and an
+  enabled-check; it encodes its args only while the profiler records.
+* a ``TraceRecorder``, when one is ``install``-ed: ``Span``s — named,
+  categorized, nested wall-clock intervals — kept in memory for the
+  operator's exports (``repro.launch.sweep --trace``). The install
+  seam follows ``repro.core.faults``: a contextmanager + ContextVar,
+  plus a module-level fallback so worker threads (the serve plane's
+  feed producer and prediction batcher) record too.
 
-* a recorder is ``install``-ed for a scope (contextmanager + ContextVar;
-  a module-level fallback makes it visible to worker threads, which
-  do not inherit ContextVars — the serve plane's feed producer and
-  prediction batcher record through it);
-* instrumented code calls the module-level ``span(category, ...)``;
-* with nothing installed, ``span`` returns one shared reusable no-op
-  context — no allocation, no lock, one ContextVar read. Nothing is
-  ever recorded from inside jit: spans are host-side wall intervals
-  only, so compiled numerics are untouched and the default path is
-  bitwise-identical (the same discipline as the faults seam).
+Nothing is ever recorded from inside jit: spans are host-side
+intervals, so compiled numerics are untouched and traced runs are
+bitwise-identical to untraced ones. The device work of a round is named
+from inside the jitted programs instead, by ``jax.named_scope``
+(``bundle_gram``, ``allreduce_gv``, ``corrections``, ``update``,
+``param_avg``, ``loss_probe``; see ``repro.core.engine``).
 
 Span categories are a closed set (``SPAN_CATEGORIES``); an unknown
-category is a programming error and raises immediately. The mapping to
-the paper: ``bundle_compute`` is Eq. 4's γ (compute) term,
-``allreduce_gv`` the per-bundle (G, v) Allreduce (α/β over p_c),
-``param_avg`` the per-τ weight averaging (α/β over p_r) — the three
-phases §6.5 calibrates. Under a delay-D schedule ``allreduce_gv``
-splits into ``allreduce_gv_issue`` (the host-side dispatch cost that
-stays on the critical path) and ``allreduce_gv_await`` (the exposed
-remainder after D bundle-computes of overlap) — Perfetto shows the
-bubble closing as D grows. ``round``/``compile`` wrap the session chunk
-loop; ``ckpt_save``/``ckpt_verify``/``swap`` the durability plane;
-``ingest``/``predict_batch`` the serve plane.
+category is a programming error and raises immediately. ``step`` wraps
+one ``Session.step_rounds`` call; ``round``/``compile`` each sub-chunk
+advance inside it; ``probe`` the loss probe (dispatch + device-to-host
+read); ``copy_x`` the host copy of the weights a ``RoundEvent`` or
+checkpoint carries; ``ckpt_save``/``ckpt_verify``/``swap`` the
+durability plane; ``ingest``/``predict_batch`` the serve plane.
 """
 
 from __future__ import annotations
@@ -45,6 +42,8 @@ import dataclasses
 import threading
 import time
 from contextvars import ContextVar
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 __all__ = [
     "SPAN_CATEGORIES",
@@ -56,12 +55,10 @@ __all__ = [
 ]
 
 SPAN_CATEGORIES = (
+    "step",
     "round",
-    "bundle_compute",
-    "allreduce_gv",
-    "allreduce_gv_issue",
-    "allreduce_gv_await",
-    "param_avg",
+    "probe",
+    "copy_x",
     "ckpt_save",
     "ckpt_verify",
     "swap",
@@ -141,25 +138,6 @@ class TraceRecorder:
                 args=args,
             ))
 
-    def add_span(self, category: str, name: str, *, t0: float | None = None,
-                 dur: float, **args) -> Span:
-        """Record an externally-measured interval (phase probes, compile
-        walls) post hoc. ``t0`` defaults to now-minus-``dur``."""
-        if category not in SPAN_CATEGORIES:
-            raise ValueError(f"category={category!r} not in {SPAN_CATEGORIES}")
-        now = time.perf_counter() - self.epoch_perf
-        s = Span(
-            category=category,
-            name=name,
-            t0=(now - dur) if t0 is None else t0,
-            dur=float(dur),
-            tid=threading.get_ident(),
-            depth=self._depth(),
-            args=args,
-        )
-        self._append(s)
-        return s
-
     def _append(self, s: Span) -> None:
         with self._lock:
             self.spans.append(s)
@@ -194,10 +172,6 @@ class TraceRecorder:
 _ACTIVE: ContextVar[TraceRecorder | None] = ContextVar("trace_recorder", default=None)
 _GLOBAL: TraceRecorder | None = None
 
-# one shared, reusable no-op context: the uninstalled fast path must not
-# allocate per call (the round loop crosses it every sub-chunk).
-_NULLCTX = contextlib.nullcontext()
-
 
 def active() -> TraceRecorder | None:
     """The installed recorder, or None (the normal, untraced case)."""
@@ -225,9 +199,24 @@ def install(recorder: TraceRecorder | None = None):
 
 
 def span(category: str, name: str | None = None, **args):
-    """Record a span at an instrumented site — the shared no-op context
-    when no recorder is installed."""
+    """A span at an instrumented site: a ``repro.<category>`` annotation
+    in any running ``jax.profiler`` trace, with ``name`` and ``args`` as
+    its stats, and a ``Span`` in the installed recorder, if any.
+    ``step`` spans take a ``step_num`` arg (xprof's step number)."""
+    if category not in SPAN_CATEGORIES:
+        raise ValueError(f"category={category!r} not in {SPAN_CATEGORIES}")
+    if category == "step":
+        ann = StepTraceAnnotation("repro.step", **args)
+    else:
+        ann = TraceAnnotation(f"repro.{category}",
+                              name=category if name is None else name, **args)
     rec = active()
     if rec is None:
-        return _NULLCTX
-    return rec.span(category, name, **args)
+        return ann
+    return _both(ann, rec.span(category, name, **args))
+
+
+@contextlib.contextmanager
+def _both(annotation, recorded):
+    with annotation, recorded as rec:
+        yield rec
