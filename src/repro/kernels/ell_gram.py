@@ -14,13 +14,21 @@ built on the fly from the ELL (indices, values) pair:
 
 The panel build is a compare-against-iota one-hot contraction — an MXU/
 VPU-friendly formulation of scatter (Pallas TPU has no in-kernel
-scatter). Cost per bundle is O(sb·w·n) for the expansion plus
-O(sb²·n) for the syrk, vs O(sb·n) HBM *traffic* for the dense path —
-on TPU the expansion is compute against VMEM-resident data, while the
-dense path is a scatter into HBM plus a full re-stream. Arithmetic
-caveat: the expansion term dominates the syrk when the ELL width w
-exceeds sb, so heavy-tailed rows (w ≫ s·b, e.g. the url dataset)
-favor a wider bundle or the dense oracle off-TPU — benchmarks
+scatter).
+
+Column compaction (``_compact_columns``, shared by both backends): a
+bundle touches at most sb·w distinct columns, so where those fit in
+fewer panels than n the bundle's columns are first renumbered by one
+sort of its sb·w indices, and x is gathered in that order; the walk
+then covers ⌈sb·w/bk⌉ panels instead of ⌈n/bk⌉ (news20 at s·b = 64:
+61, not 2,647). Columns keep their global order, so only their grouping
+into panels changes. Narrow problems (sb·w ≥ n in panels) trace the
+direct walk unchanged. Cost per bundle is O(sb·w·min(n, sb·w)) for the
+expansion plus O(sb²·min(n, sb·w)) for the syrk, and one sort of sb·w
+keys — on TPU the expansion is compute against VMEM-resident data.
+Arithmetic caveat: the expansion term dominates the syrk when the ELL
+width w exceeds sb, so heavy-tailed rows (w ≫ s·b, e.g. the url
+dataset) favor a wider bundle or the dense oracle off-TPU — benchmarks
 bench_kernels.py measures both sides.
 
 The strict-lower mask (only l < j corrections are applied by the s-step
@@ -61,6 +69,51 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.obs import metrics as obs_metrics
+
+_PANELS_GAUGE = "ell_gram.panels_per_call"
+
+
+def panels_walked(rows: int, width: int, n: int, bk: int) -> int:
+    """Column panels one (G, v) call walks at panel width bk: ⌈n/bk⌉, or
+    the ⌈rows·width/bk⌉ of its compacted columns where those are fewer."""
+    return min(-(-n // bk), -(-(rows * width) // bk))
+
+
+def _compact_columns(indices, x, n: int, bk: int):
+    """Shared preamble for both backends: renumber the bundle's columns
+    so the panel walk covers only the columns it touches.
+
+    A bundle of sb rows of width w touches at most U = sb·w distinct
+    columns. Where ⌈U/bk⌉ < ⌈n/bk⌉ (shapes are static, so this is a
+    trace-time choice) the column ids are sorted, each entry gets the
+    sorted position of its column's first occurrence as a local id in
+    [0, U), and x is gathered in sorted order: the walk then has ⌈U/bk⌉
+    panels. Columns keep their global order, so only their grouping into
+    panels changes. A slot that holds a repeat of a column is referenced
+    by no entry, so its panel column is zero; ELL pad entries (idx 0,
+    val 0) share column 0's slot and add nothing. Otherwise the inputs
+    pass through untouched and the direct walk is traced as before.
+
+    Sets the trace-time gauge ``ell_gram.panels_per_call`` (labelled
+    ``path=compacted|direct``) to the panels the traced call walks."""
+    sb, w = indices.shape
+    u = sb * w
+    panels = panels_walked(sb, w, n, bk)
+    if panels == -(-n // bk):
+        obs_metrics.registry().gauge(_PANELS_GAUGE, path="direct").set(panels)
+        return indices, x, n
+    obs_metrics.registry().gauge(_PANELS_GAUGE, path="compacted").set(panels)
+    pos = jax.lax.iota(jnp.int32, u)
+    cols, order = jax.lax.sort((indices.reshape(u), pos), num_keys=1)
+    first = jnp.concatenate([jnp.ones((1,), bool), cols[1:] != cols[:-1]])
+    slot = jax.lax.cummax(jnp.where(first, pos, 0))
+    # back to entry order by a second sort, which a v5e runs faster
+    # than a scatter of the same pairs
+    _, local = jax.lax.sort((order, slot), num_keys=1)
+    x_c = x.at[cols].get(mode="promise_in_bounds", indices_are_sorted=True)
+    return local.reshape(sb, w), x_c, u
 
 
 def _prep_panels(values, x, n: int, bk: int):
@@ -218,6 +271,7 @@ def ell_gram_and_v(
     takes the platform's mode (``default_interpret``).
     """
     sb, w = values.shape
+    indices, x, n = _compact_columns(indices, x, n, bk)
     acc, x, n_panels = _prep_panels(values, x, n, bk)
     cd = compute_dtype_for(precision)
     interpret = default_interpret() if interpret is None else interpret
@@ -265,6 +319,7 @@ def ell_gram_and_v_blocked(
     panel. The autotuner times it where the kernel would only run in
     the interpreter."""
     sb, w = values.shape
+    indices, x, n = _compact_columns(indices, x, n, bk)
     acc, x, n_panels = _prep_panels(values, x, n, bk)
     cd = compute_dtype_for(precision)
 

@@ -73,7 +73,7 @@ log = logging.getLogger("repro.kernels.tune")
 
 # Bump when ell_gram / sstep_inner math or tiling changes: the cache key
 # folds this in, so every stale winner misses at once.
-KERNEL_VERSION = 2
+KERNEL_VERSION = 3
 
 BK_CANDIDATES = (128, 256, 512, 1024)
 BM_CANDIDATES = (None, 16, 32)
@@ -92,8 +92,9 @@ class PanelProfile:
               *mean* nnz/row: deterministic from stats, so plan() and
               the build agree; the max-width heavy-tail decision is
               separate, see ``select_gram_path``).
-    n_local   per-shard column count ⌈n/p_c⌉ — the kernel's panel-walk
-              extent.
+    n_local   per-shard column count ⌈n/p_c⌉ — the kernel walks
+              min(⌈n_local/bk⌉, ⌈rows·width/bk⌉) panels (it compacts a
+              bundle's columns where they fit in fewer panels than n).
     dense     registry dense flag (epsilon-style data: width = n).
     precision schedule precision ("fp32" | "bf16") — changes the MXU
               peak and the VMEM tile, so it is part of the key.

@@ -24,6 +24,8 @@ from __future__ import annotations
 import dataclasses
 import re
 
+from repro.kernels.ell_gram import panels_walked
+
 
 @dataclasses.dataclass(frozen=True)
 class ChipPeaks:
@@ -219,7 +221,10 @@ class RooflineTerms:
 
 # ---- analytic panel roofline (repro.kernels.tune's justification) ----
 #
-# The ELL-Gram kernel walks ⌈n/bk⌉ column panels; per panel it expands
+# The ELL-Gram kernel walks min(⌈n/bk⌉, ⌈rows·width/bk⌉) column panels:
+# a bundle touches at most rows·width distinct columns, and where those
+# fit in fewer panels than n the kernel compacts them first
+# (``repro.kernels.ell_gram.panels_walked``). Per panel it expands
 # the (sb, w) ELL block into a (sb, bk) dense panel (one-hot contraction,
 # 2·sb·w·bk FLOPs), accumulates G += P·Pᵀ (2·sb²·bk) and v += P·x_blk
 # (2·sb·bk). The ELL block itself is re-streamed from HBM once per panel
@@ -242,7 +247,7 @@ def panel_vmem_bytes(
 
 def panel_flops(rows: int, width: int, n: int, bk: int) -> float:
     """Total FLOPs of one (G, v) bundle build at panel width bk."""
-    n_panels = -(-n // bk)
+    n_panels = panels_walked(rows, width, n, bk)
     per_panel = 2 * rows * width * bk + 2 * rows * rows * bk + 2 * rows * bk
     return float(n_panels * per_panel)
 
@@ -252,7 +257,7 @@ def panel_hbm_bytes(
 ) -> float:
     """HBM traffic of one bundle build: the ELL block re-streamed once
     per panel, x streamed once, G and v written once."""
-    n_panels = -(-n // bk)
+    n_panels = panels_walked(rows, width, n, bk)
     ell = n_panels * rows * width * (4 + 4)  # int32 indices + f32 values
     x = n_panels * bk * 4
     out = rows * rows * 4 + rows * 4

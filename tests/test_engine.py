@@ -237,3 +237,27 @@ def test_engine_rejects_mismatched_teams(dataset):
     tp = stack_row_teams(a, y, 4, row_multiple=B)
     with pytest.raises(ValueError):
         run_parallel_sgd(tp, jnp.zeros(tp.n), ParallelSGDSchedule.fedavg(2, B, ETA, 8, 1))
+
+
+def test_engine_round_with_compacted_columns_matches_dense():
+    """A round whose bundles touch far fewer columns than n (s·b·w = 384
+    of n = 8,192) walks compacted panels and follows the dense oracle."""
+    from repro.obs import metrics as obs_metrics
+
+    rng = np.random.default_rng(4)
+    a = make_skewed_csr(256, 8192, 6, 0.8, seed=5)
+    y = np.where(rng.random(256) < 0.5, 1.0, -1.0)
+    s, tau = 4, 16
+    tp = stack_row_teams(a, y, 2, row_multiple=s * B)
+    assert s * B * tp.indices.shape[-1] < tp.n
+    x0 = jnp.zeros(tp.n)
+    base = ParallelSGDSchedule.hybrid(2, s, B, ETA, tau, rounds=1)
+    compacted = obs_metrics.registry().gauge("ell_gram.panels_per_call", path="compacted")
+    compacted.set(-1)
+    x_pallas, _ = run_parallel_sgd(tp, x0, base)
+    assert compacted.value == 1  # ⌈384 / 512⌉, against ⌈8192 / 512⌉ = 16
+    assert np.abs(np.asarray(x_pallas)).max() > 0
+    x_dense, _ = run_parallel_sgd(tp, x0, dataclasses.replace(base, gram="dense"))
+    np.testing.assert_allclose(
+        np.asarray(x_pallas), np.asarray(x_dense), rtol=1e-6, atol=1e-7
+    )

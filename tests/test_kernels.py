@@ -167,3 +167,88 @@ def test_f32_panel_is_exact(split_f32):
     want = np.zeros((rows, bk), np.float32)
     np.put_along_axis(want, idx, val, axis=1)
     np.testing.assert_array_equal(np.asarray(panel), want)
+
+
+def _wide_bundle():
+    """A bundle that touches far fewer columns than n: sb·w = 96 < n =
+    5,000. Rows of unequal length (ELL pad entries: idx 0, val 0), one
+    column shared by many rows, the last column n−1, and small-integer
+    values, so every sum of products is exact in f32 whatever the order."""
+    rng = np.random.default_rng(11)
+    sb, w, n = 16, 6, 5_000
+    idx = rng.integers(0, n, size=(sb, w)).astype(np.int32)
+    val = rng.integers(-4, 5, size=(sb, w)).astype(np.float32)
+    for r in range(sb):  # row r keeps 1 + r % w entries
+        idx[r, 1 + r % w:] = 0
+        val[r, 1 + r % w:] = 0.0
+    idx[1:9, 0] = 777  # a column shared by eight rows
+    idx[0, 0], val[0, 0] = n - 1, 3.0  # the last column
+    x = rng.standard_normal(n).astype(np.float32)
+    return jnp.asarray(idx), jnp.asarray(val), jnp.asarray(x), n
+
+
+@pytest.mark.parametrize("impl", [ell_gram_and_v, ell_gram_and_v_blocked],
+                         ids=["pallas", "blocked"])
+def test_compacted_walk_matches_full_walk(impl, monkeypatch):
+    """Compacting the bundle's columns changes only how they are grouped
+    into panels: G is the full walk's bit for bit on exact data, v within
+    f32 rounding, and both match the dense oracle."""
+    import repro.kernels.ell_gram as ell_gram
+
+    idx, val, x, n = _wide_bundle()
+    g_c, v_c = impl(idx, val, x, n=n, bk=32)
+    monkeypatch.setattr(ell_gram, "_compact_columns", lambda i, z, n, bk: (i, z, n))
+    g_full, v_full = impl(idx, val, x, n=n, bk=32)
+    np.testing.assert_array_equal(np.asarray(g_c), np.asarray(g_full))
+    np.testing.assert_allclose(np.asarray(v_c), np.asarray(v_full), rtol=1e-6, atol=1e-5)
+    g_ref, v_ref = ref.ell_gram_and_v_ref(idx, val, x, n)
+    np.testing.assert_allclose(np.asarray(g_c), np.asarray(g_ref), rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(v_c), np.asarray(v_ref), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("impl", [ell_gram_and_v, ell_gram_and_v_blocked],
+                         ids=["pallas", "blocked"])
+def test_direct_walk_traces_no_sort(impl):
+    """Where the bundle could touch every panel (sb·w ≥ n) the walk is
+    traced as it always was: no sort, no gather of x. A wide n does
+    compact, through one sort."""
+    import jax
+
+    def prims(sb, w, n):
+        args = (jnp.zeros((sb, w), jnp.int32), jnp.ones((sb, w)), jnp.ones(n))
+        jaxpr = jax.make_jaxpr(lambda i, v, z: impl(i, v, z, n=n, bk=128))(*args)
+        return {e.primitive.name for e in jaxpr.jaxpr.eqns}
+
+    narrow = prims(16, 8, 100)
+    assert "sort" not in narrow and "gather" not in narrow
+    assert "sort" in prims(16, 8, 5_000)
+
+
+@pytest.mark.parametrize(
+    "sb,w,n,bk,path,panels",
+    [(16, 6, 5_000, 32, "compacted", 3), (64, 483, 1_355_191, 512, "compacted", 61),
+     (16, 8, 100, 128, "direct", 1), (64, 31, 2_000, 128, "direct", 16)],
+    ids=["small-compacted", "news20-compacted", "narrow-direct", "equal-panels-direct"],
+)
+def test_panels_per_call_gauge(sb, w, n, bk, path, panels):
+    """The trace-time gauge says which walk a traced call takes and how
+    many panels it has: ⌈sb·w/bk⌉ compacted, ⌈n/bk⌉ direct."""
+    import jax
+
+    from repro.obs import metrics as obs_metrics
+
+    gauges = {
+        p: obs_metrics.registry().gauge("ell_gram.panels_per_call", path=p)
+        for p in ("compacted", "direct")
+    }
+    for g in gauges.values():
+        g.set(-1)
+    args = (
+        jax.ShapeDtypeStruct((sb, w), jnp.int32),
+        jax.ShapeDtypeStruct((sb, w), jnp.float32),
+        jax.ShapeDtypeStruct((n,), jnp.float32),
+    )
+    jax.make_jaxpr(lambda i, v, z: ell_gram_and_v(i, v, z, n=n, bk=bk))(*args)
+    assert {p: g.value for p, g in gauges.items()} == {
+        p: (panels if p == path else -1) for p in gauges
+    }

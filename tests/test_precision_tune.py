@@ -363,6 +363,31 @@ def test_tune_applies_the_roofline_of_the_measured_chip(tmp_path, monkeypatch):
     assert rec["efficiency"] == pytest.approx(rec["attainable_s"] / rec["measured_s"])
 
 
+def test_tune_keeps_honest_compacted_timings(tmp_path, monkeypatch):
+    """A bundle that touches fewer columns than the shard walks only the
+    compacted panels, so the roofline counts those: a timing just above
+    the compacted walk's bound is kept, not flagged as sub-roofline."""
+    from repro.kernels.ell_gram import panels_walked
+    from repro.launch.roofline import panel_roofline, peaks_for
+
+    v5e = peaks_for("TPU v5 lite")
+    rows, width, n = 16, 8, 4096
+    monkeypatch.setattr(tune, "measured_peaks", lambda: v5e)
+
+    def honest(idx, val, x, n_, bk, bm, precision, repeats):
+        # what the compacted kernel walks: a shard of rows·width columns
+        walk = panel_roofline(rows, width, rows * width, bk, bm, precision, peaks=v5e)
+        return 1.1 * walk.attainable_s
+
+    monkeypatch.setattr(tune, "_time_candidate", honest)
+    p = _profile(rows=rows, width=width, n_local=n)
+    rec = tune.tune_panel(p, cache_dir=tmp_path, repeats=1, max_n=n)
+    timed = [c for c in rec["candidates"] if c["skipped"] != "vmem"]
+    assert timed and all(c["skipped"] is None for c in timed)
+    assert all(panels_walked(rows, width, n, c["bk"]) < -(-n // c["bk"]) for c in timed)
+    assert rec["efficiency"] == pytest.approx(1 / 1.1)
+
+
 def test_peaks_are_keyed_by_device_kind():
     from repro.launch.roofline import PEAKS, peaks_for
 

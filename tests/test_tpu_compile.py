@@ -79,6 +79,24 @@ def test_ell_gram_fp32_compiles(one_chip, width):
     assert "tpu_custom_call" in _gram_text(one_chip, precision="fp32", **width)
 
 
+@pytest.mark.parametrize("width,panels", [(RCV1, 14), (NEWS20, 61)], ids=["rcv1", "news20"])
+def test_ell_gram_walks_compacted_panels(one_chip, width, panels):
+    """At both real widths a bundle touches fewer columns than n, so the
+    compiled kernel's grid is ⌈64·w/512⌉ steps (news20: 61, not the
+    2,647 of the whole n)."""
+    n = width["n"]
+    args = (
+        _sds((SB, width["w"]), jnp.int32, one_chip),
+        _sds((SB, width["w"]), jnp.float32, one_chip),
+        _sds((n,), jnp.float32, one_chip),
+    )
+    f = jax.jit(lambda i, v, x: ell_gram_and_v(i, v, x, n=n, bk=512, interpret=False))
+    eqns = jax.make_jaxpr(f)(*args).jaxpr.eqns[0].params["jaxpr"].eqns
+    grids = [e.params["grid_mapping"].grid for e in eqns if e.primitive.name == "pallas_call"]
+    assert grids == [(panels,)] and panels == -(-SB * width["w"] // 512) < -(-n // 512)
+    assert "tpu_custom_call" in f.lower(*args).compile().as_text()
+
+
 def test_ell_gram_bf16_compiles(one_chip):
     assert "tpu_custom_call" in _gram_text(one_chip, precision="bf16", **RCV1)
 
