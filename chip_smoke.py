@@ -62,7 +62,9 @@ def rel_l2(x, ref) -> float:
 
 def timed_run(spec, label: str):
     """Build a Session for ``spec``, compile its round, run it, and
-    print what it took. Returns (session, report, round HLO text)."""
+    print what it took. Returns (session, loss at x0 = 0, report, round
+    HLO text); the start loss is the Session's own, as its probe
+    computes it on either backend."""
     from repro.api import Session
 
     t0 = time.perf_counter()
@@ -71,6 +73,7 @@ def timed_run(spec, label: str):
     t0 = time.perf_counter()
     hlo = sess.compile_round().as_text()
     aot_s = time.perf_counter() - t0
+    loss0 = sess.report().final_loss
     rep = sess.run()
     first = spec.schedule.loss_every or spec.schedule.rounds
     steady = rep.rounds_completed - first
@@ -82,23 +85,14 @@ def timed_run(spec, label: str):
         f"smoke rounds/s={rate:.2f} (smoke timing, not a benchmark)",
         flush=True,
     )
-    return sess, rep, hlo
-
-
-def start_loss(sess) -> float:
-    import jax.numpy as jnp
-
-    from repro.core.problem import problem_loss
-
-    gp = sess.bundle.global_problem
-    return float(problem_loss(gp, jnp.zeros(gp.n, jnp.float32)))
+    return sess, loss0, rep, hlo
 
 
 def one_chip(spec) -> None:
     from repro.api import plan
 
     print(f"[plan ] {plan(spec).summary()}", flush=True)
-    sess, rep, hlo = timed_run(spec, "pallas")
+    sess, loss0, rep, hlo = timed_run(spec, "pallas")
     a, team = sess.bundle.dataset.A, sess.bundle.team
     print(
         f"[data ] {spec.dataset}: m={a.m} n={a.n} nnz={a.nnz} "
@@ -106,14 +100,13 @@ def one_chip(spec) -> None:
         flush=True,
     )
     check("tpu_custom_call" in hlo, "compiled round runs the Pallas kernel (tpu_custom_call)")
-    loss0 = start_loss(sess)
     print(f"[loss ] start={loss0:.6f} end={rep.final_loss:.6f} trace={rep.losses.tolist()}")
     check(rep.final_loss < loss0, "loss fell below its value at x0 = 0")
 
     dense = dataclasses.replace(
         spec, schedule=dataclasses.replace(spec.schedule, gram="dense")
     )
-    _, rep_d, _ = timed_run(dense, "dense oracle")
+    _, _, rep_d, _ = timed_run(dense, "dense oracle")
     gap = rel_l2(rep.x, rep_d.x)
     print(f"[gap  ] ||x - x_dense|| / ||x_dense|| = {gap:.3e} (tolerance {REL_TOL:.0e})")
     check(gap <= REL_TOL, "weights match the gram='dense' oracle")
@@ -126,15 +119,14 @@ def four_chips(spec) -> None:
     mesh = dataclasses.replace(
         spec, mesh=MeshSpec(p_r=p_r, p_c=2, backend="shard_map")
     )
-    sess, rep, hlo = timed_run(mesh, f"shard_map {p_r}x2")
+    _, loss0, rep, hlo = timed_run(mesh, f"shard_map {p_r}x2")
     check("tpu_custom_call" in hlo, "mesh round runs the Pallas kernel (tpu_custom_call)")
     check("all-reduce" in hlo, "mesh round holds an all-reduce")
-    loss0 = start_loss(sess)
     print(f"[loss ] start={loss0:.6f} end={rep.final_loss:.6f} trace={rep.losses.tolist()}")
     check(rep.final_loss < loss0, "loss fell below its value at x0 = 0")
 
     sim = dataclasses.replace(spec, mesh=MeshSpec(p_r=p_r, p_c=2, backend="simulated"))
-    _, rep_s, _ = timed_run(sim, "simulated, one device")
+    _, _, rep_s, _ = timed_run(sim, "simulated, one device")
     gap = rel_l2(rep.x, rep_s.x)
     print(f"[gap  ] ||x_mesh - x_sim|| / ||x_sim|| = {gap:.3e} (tolerance {REL_TOL:.0e})")
     check(gap <= REL_TOL, "shard_map weights match the simulated backend")

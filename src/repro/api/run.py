@@ -36,7 +36,6 @@ from repro.api.spec import ExperimentSpec
 from repro.core.distributed import Hybrid2DProblem, build_2d_problem
 from repro.sparse.partition import ColumnPartition
 from repro.core.objective import get_objective
-from repro.core.problem import Problem, make_problem
 from repro.core.teams import TeamProblem, stack_row_teams
 from repro.sparse.synthetic import SyntheticDataset, make_dataset
 
@@ -45,13 +44,11 @@ from repro.sparse.synthetic import SyntheticDataset, make_dataset
 class ProblemBundle:
     """Everything ``run`` needs, built once from the spec.
 
-    Exactly one of (team, prob2d) is populated, per the backend; the
-    global problem is always present (loss traces + final objective).
+    Exactly one of (team, prob2d) is populated, per the backend.
     """
 
     spec: ExperimentSpec
     dataset: SyntheticDataset
-    global_problem: Problem
     row_multiple: int
     team: TeamProblem | None = None
     prob2d: Hybrid2DProblem | None = None
@@ -85,8 +82,7 @@ def build_problem(spec: ExperimentSpec) -> ProblemBundle:
     ds = _cached_dataset(spec.dataset, seed=spec.seed)
     rm = spec.row_multiple or sched.s * sched.b
     obj = get_objective(spec.objective, l2=spec.l2)
-    gp = make_problem(ds.A, ds.y, row_multiple=rm, objective=obj)
-    bundle = ProblemBundle(spec=spec, dataset=ds, global_problem=gp, row_multiple=rm)
+    bundle = ProblemBundle(spec=spec, dataset=ds, row_multiple=rm)
     if mesh.backend == "simulated":
         bundle.team = stack_row_teams(
             ds.A, ds.y, mesh.p_r, row_multiple=rm, objective=obj

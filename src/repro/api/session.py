@@ -52,7 +52,6 @@ from repro.core.engine import (
     run_engine_chunk,
 )
 from repro.core.distributed import HybridDriver
-from repro.core.problem import problem_loss
 from repro.core.teams import global_problem
 from repro.obs import trace as obs_trace
 from repro.train.checkpoint import (
@@ -195,7 +194,6 @@ class Session:
                 self.bundle.cp,
                 x0,
                 self.spec.schedule,
-                loss_problem=self.bundle.global_problem,
                 comm=TIMED if self.spec.comm_timing else MESH,
             )
             self._x = None
@@ -301,7 +299,12 @@ class Session:
         })
 
     def _sample_loss(self) -> float:
-        with obs_trace.span("probe"):
+        """The full objective at the current iterate: on the mesh over
+        the placed shards (``path="mesh"``), else over the team problem
+        on the device (``path="device"``). Either way the one transfer
+        to the host is the float32 scalar."""
+        path = "device" if self._driver is None else "mesh"
+        with obs_trace.span("probe", path=path):
             if self._driver is not None:
                 return self._driver.loss()
             return float(engine_loss(self._gp, self._x))
@@ -610,7 +613,10 @@ class Session:
     def report(self) -> RunReport:
         """The uniform ``RunReport`` for the rounds completed so far."""
         x = self.current_x()
-        final_loss = float(problem_loss(self.bundle.global_problem, jnp.asarray(x)))
+        if self._driver is not None:
+            final_loss = self._driver.loss()
+        else:
+            final_loss = float(engine_loss(self._gp, self._x))
         return RunReport(
             spec=self.spec,
             plan=self._plan,

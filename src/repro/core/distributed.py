@@ -49,22 +49,22 @@ from repro.core.engine import (
     bundle_gram_v,
     check_delay,
     delayed_bundle_scan,
-    engine_loss,
     inner_corrections,
     unwire_gv,
     wire_gv,
 )
 from repro.core.objective import LOGISTIC, Objective, get_objective
-from repro.core.problem import Problem
 from repro.sparse.csr import CSRMatrix
-from repro.sparse.ell import EllBlock, ell_rmatvec
+from repro.sparse.ell import EllBlock, ell_matvec, ell_rmatvec
 from repro.sparse.partition import ColumnPartition, partition_columns, partition_rows
 
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class Hybrid2DProblem:
-    """Device-layout HybridSGD problem.
+    """HybridSGD problem in the mesh's layout, held on the host until
+    ``HybridDriver`` puts each block on its device (so no one device
+    ever holds the whole matrix).
 
     indices/values: (p_r, p_c, rows_local, width) — ELL blocks, column
     ids local to each column shard.
@@ -72,9 +72,9 @@ class Hybrid2DProblem:
     n_loc = max(col_sizes).
     """
 
-    indices: jnp.ndarray
-    values: jnp.ndarray
-    col_sizes: jnp.ndarray
+    indices: np.ndarray
+    values: np.ndarray
+    col_sizes: np.ndarray
     p_r: int = dataclasses.field(metadata=dict(static=True))
     p_c: int = dataclasses.field(metadata=dict(static=True))
     m: int = dataclasses.field(metadata=dict(static=True))
@@ -135,9 +135,9 @@ def build_2d_problem(
                 idx[i, j, r, :k] = blk.indices[lo:hi]
                 val[i, j, r, :k] = blk.data[lo:hi]
     prob = Hybrid2DProblem(
-        indices=jnp.asarray(idx),
-        values=jnp.asarray(val, dtype=dtype),
-        col_sizes=jnp.asarray(np.asarray(cp.n_local, np.int32)),
+        indices=idx,
+        values=val.astype(np.dtype(dtype)),
+        col_sizes=np.asarray(cp.n_local, np.int32),
         p_r=p_r,
         p_c=p_c,
         m=a.m,
@@ -376,6 +376,40 @@ def make_hybrid_step(
     return step
 
 
+def make_hybrid_loss(mesh: Mesh, prob: Hybrid2DProblem):
+    """Return a jitted fn (indices, values, x_pad) → f(x), the full
+    objective over the blocks as ``HybridDriver`` places them, computed
+    where they lie: each device takes its rows' partial margins over its
+    column shard, the partials are summed over "cols", each team sums
+    the losses of its valid rows, and the teams' sums are summed over
+    "rows". The l2 term sums each column shard's slice once (x is
+    replicated across "rows"). Only the scalar result leaves the mesh.
+
+    A team's rows past its true count are zero padding (margin 0, loss
+    ℓ(0), not 0), so they are masked by the same ``partition_rows``
+    bounds ``build_2d_problem`` cut the teams with."""
+    counts = np.diff(partition_rows(prob.m, prob.p_r)).tolist()
+    objective, n_loc, m = prob.objective, prob.n_loc, prob.m
+
+    def loss_fn(idx_blk, val_blk, x_loc):
+        with jax.named_scope("loss_probe"):
+            blk = EllBlock(indices=idx_blk[0, 0], values=val_blk[0, 0], n=n_loc)
+            margin = jax.lax.psum(ell_matvec(blk, x_loc), "cols")
+            losses = objective.pointwise_loss(margin)
+            team = jax.lax.axis_index("rows")
+            valid = sum(jnp.where(team == i, c, 0) for i, c in enumerate(counts))
+            losses = jnp.where(jnp.arange(losses.shape[0]) < valid, losses, 0.0)
+            f = jax.lax.psum(jnp.sum(losses), "rows") / m
+            if objective.l2:
+                f = f + 0.5 * objective.l2 * jax.lax.psum(jnp.sum(x_loc * x_loc), "cols")
+            return f
+
+    return jax.jit(shard_map(
+        loss_fn, mesh=mesh, in_specs=(P("rows", "cols"), P("rows", "cols"), P("cols")),
+        out_specs=P(),
+    ))
+
+
 class HybridDriver:
     """Round-incremental shard_map executor — the chunkable form of the
     old run-everything loop.
@@ -407,19 +441,18 @@ class HybridDriver:
         cp: ColumnPartition,
         x0: np.ndarray,
         sched: ParallelSGDSchedule,
-        loss_problem: Problem | None = None,
         rounds_done: int = 0,
         comm: Collectives = MESH,
     ):
         self.prob = prob
         self.cp = cp
         self.sched = sched
-        self.loss_problem = loss_problem
         self.rounds_done = int(rounds_done)
         self.comm = comm
         self.ledger = hybrid_comm_ledger(prob, sched, comm)
         self.ledger.rounds = self.rounds_done
         self._step = make_hybrid_step(mesh, prob, sched, comm=comm)
+        self._loss = make_hybrid_loss(mesh, prob)
         self._mesh = mesh
         data_sh = NamedSharding(mesh, P("rows", "cols"))
         self._data_sh = data_sh
@@ -542,11 +575,10 @@ class HybridDriver:
         )
 
     def loss(self) -> float:
-        """Full global objective (under ``loss_problem``'s objective)
-        at the current iterate."""
-        if self.loss_problem is None:
-            raise ValueError("HybridDriver was built without loss_problem")
-        return float(engine_loss(self.loss_problem, jnp.asarray(self.gather())))
+        """Full global objective (under the problem's objective) at the
+        current iterate, computed on the mesh over the placed blocks
+        (``make_hybrid_loss``); only the scalar comes to the host."""
+        return float(self._loss(self._idx, self._val, self._x_pad))
 
 
 def run_hybrid_distributed(
@@ -562,7 +594,6 @@ def run_hybrid_distributed(
     gram: str | None = None,
     *,
     s: int | None = None,
-    loss_problem: Problem | None = None,
 ):
     """Driver: place data once, run ``sched.rounds`` rounds, gather x.
 
@@ -570,9 +601,7 @@ def run_hybrid_distributed(
     loss-sampling chunk. Returns ``(x, losses)`` — the same contract as
     the simulated engine's ``run_parallel_sgd``: the full global
     objective is sampled every ``sched.loss_every`` rounds (empty trace
-    when 0). Sampling the loss needs the global problem, so pass
-    ``loss_problem`` (the repro.api front door wires this
-    automatically).
+    when 0), on the mesh by ``HybridDriver.loss``.
 
     The legacy signature ``run_hybrid_distributed(mesh, prob, cp, x0,
     s, b, eta, tau, rounds, gram=...)`` still works (returning bare
@@ -592,10 +621,7 @@ def run_hybrid_distributed(
         _reject_scalars_with_schedule(
             "run_hybrid_distributed", s=s, b=b, eta=eta, tau=tau, rounds=rounds, gram=gram
         )
-    if sched.loss_every and loss_problem is None:
-        raise ValueError("loss_every > 0 needs loss_problem (the global Problem)")
-
-    driver = HybridDriver(mesh, prob, cp, x0, sched, loss_problem=loss_problem)
+    driver = HybridDriver(mesh, prob, cp, x0, sched)
     losses = []
     chunk = sched.loss_every if sched.loss_every else sched.rounds
     while driver.rounds_done < sched.rounds:

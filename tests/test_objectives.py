@@ -44,6 +44,7 @@ from repro.core import (
     run_parallel_sgd,
     stack_row_teams,
 )
+from repro.core.objective import exp_nonpositive
 from repro.kernels.ref import densify_bundle_ref, ell_gram_and_v_ref
 from repro.sparse.synthetic import make_skewed_csr
 
@@ -76,6 +77,39 @@ def test_residual_is_negative_loss_gradient(name):
     np.testing.assert_allclose(
         np.asarray(obj.residual(z)), -np.asarray(grad), rtol=1e-5, atol=1e-6
     )
+
+
+def _ulps(got, want64):
+    """Signed errors of float32 ``got`` against float64 ``want64``, in
+    units of float32's spacing at the true value."""
+    spacing = np.spacing(np.abs(want64.astype(np.float32))).astype(np.float64)
+    return (np.asarray(got, np.float64) - want64) / spacing
+
+
+def test_exp_nonpositive_is_float32_accurate_and_unbiased():
+    """The logistic residual's exp: within 1.5 ulps of float64 over
+    [-87, 0] and unbiased (a v5e's own float32 exp is 16 ulps off on
+    average and low by 10), exactly 1 at 0, and clamped to exp(-87)
+    below (float32's least normal, not a denormal)."""
+    rng = np.random.default_rng(0)
+    t = -np.concatenate([rng.uniform(0, 87, 1 << 18), rng.uniform(0, 1, 1 << 18)])
+    t = t.astype(np.float32)
+    err = _ulps(jax.jit(exp_nonpositive)(t), np.exp(t.astype(np.float64)))
+    assert np.abs(err).max() <= 1.5 and abs(err.mean()) < 0.05
+    edge = np.asarray(exp_nonpositive(jnp.asarray([0.0, -87.0, -200.0, -np.inf])))
+    assert edge[0] == 1.0 and np.all(edge[1:] == edge[1]) and edge[1] > 0
+    assert np.isnan(float(exp_nonpositive(jnp.float32(np.nan))))
+
+
+def test_logistic_residual_is_float32_accurate():
+    """u(z) = 1/(1 + e^z) from one exp of -|z|: within 3 ulps of float64
+    on both branches, and exactly ½ at 0."""
+    z = np.random.default_rng(1).uniform(-30, 30, 1 << 18).astype(np.float32)
+    z64 = z.astype(np.float64)
+    want = np.where(z64 >= 0, np.exp(-z64) / (1 + np.exp(-z64)), 1 / (1 + np.exp(z64)))
+    err = _ulps(jax.jit(LOGISTIC.residual)(z), want)
+    assert np.abs(err).max() <= 3 and abs(err.mean()) < 0.05
+    assert float(LOGISTIC.residual(jnp.float32(0.0))) == 0.5
 
 
 @pytest.mark.parametrize("name,l2", OBJ_POINTS)
@@ -212,7 +246,9 @@ def test_spec_end_to_end_simulated(name, l2):
     np.testing.assert_array_equal(rep.losses, rep2.losses)
     # and the engine really optimizes this objective
     bundle = build_problem(spec)
-    f0 = float(problem_loss(bundle.global_problem, jnp.zeros(bundle.dataset.A.n)))
+    whole = make_problem(bundle.dataset.A, bundle.dataset.y, row_multiple=bundle.row_multiple,
+                         objective=get_objective(name, l2=l2))
+    f0 = float(problem_loss(whole, jnp.zeros(bundle.dataset.A.n)))
     assert rep.final_loss < f0
 
 

@@ -111,7 +111,6 @@ import jax, numpy as np
 sys.path.insert(0, {tests!r})
 from test_obs_profile import host_events, scopes_in, spec
 from repro.api import Session
-from repro.core.engine import engine_loss
 
 a = Session(spec("shard_map"))
 while not a.done:
@@ -125,9 +124,9 @@ with tempfile.TemporaryDirectory() as td:
     finally:
         jax.profiler.stop_trace()
     events = host_events(td)
-step = b._driver.lower_step().as_text(dialect="hlo", debug_info=True)
-x = np.zeros(b.bundle.dataset.A.n, np.float32)
-loss = engine_loss.lower(b.bundle.global_problem, x).as_text(dialect="hlo", debug_info=True)
+d = b._driver
+step = d.lower_step().as_text(dialect="hlo", debug_info=True)
+loss = d._loss.lower(d._idx, d._val, d._x_pad).as_text(dialect="hlo", debug_info=True)
 print("MESH_RESULT", json.dumps({{
     "bitwise": bool(np.array_equal(a.current_x(), b.current_x())
                     and np.array_equal(np.asarray(a.losses), np.asarray(b.losses))),

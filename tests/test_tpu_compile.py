@@ -158,3 +158,50 @@ def test_engine_round_compiles_with_the_kernel(one_chip, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
     dense = lower_engine_chunk(tp, x, 1, dataclasses.replace(sched, gram="dense"))
     assert "tpu_custom_call" not in dense.compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def rcv1_2x2(topo):
+    """The ``rcv1-hybrid-2x2`` layout on the described 2x2 mesh: 2 teams
+    of 10,176 rows (10,121 valid, padded to s·b), 2 cyclic column shards
+    of 23,618, ELL width 64 a shard."""
+    from repro.core.distributed import Hybrid2DProblem
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("rows", "cols"))
+    data = NamedSharding(mesh, P("rows", "cols"))
+    shape = (2, 2, 10_176, 64)
+    prob = Hybrid2DProblem(
+        indices=_sds(shape, jnp.int32, data), values=_sds(shape, jnp.float32, data),
+        col_sizes=_sds((2,), jnp.int32, NamedSharding(mesh, P())),
+        p_r=2, p_c=2, m=20_242, n=RCV1["n"], n_loc=23_618,
+    )
+    x = _sds((2 * 23_618,), jnp.float32, NamedSharding(mesh, P("cols")))
+    return mesh, prob, x
+
+
+def test_mesh_round_compiles_with_the_kernel(rcv1_2x2, monkeypatch):
+    """One full-size round of the shard_map backend: the kernel per
+    shard, the (G, v) psum and the row-team pmean."""
+    import repro.kernels.ell_gram as ell_gram
+    from repro.core.distributed import make_hybrid_step
+    from repro.core.engine import ParallelSGDSchedule
+
+    monkeypatch.setattr(ell_gram, "default_interpret", lambda: False)
+    mesh, prob, x = rcv1_2x2
+    step = make_hybrid_step(mesh, prob, ParallelSGDSchedule.hybrid(2, 8, 8, 0.5, 32, rounds=1))
+    text = step.lower(prob.indices, prob.values, x,
+                      jax.ShapeDtypeStruct((), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert text.count("all-reduce(") + text.count("all-reduce-start(") >= 2
+
+
+def test_mesh_loss_compiles_to_a_replicated_scalar(rcv1_2x2):
+    """The mesh loss probe: partial margins summed over "cols", team
+    sums over "rows", one f32 scalar out; no gather of x."""
+    from repro.core.distributed import make_hybrid_loss
+
+    mesh, prob, x = rcv1_2x2
+    compiled = make_hybrid_loss(mesh, prob).lower(prob.indices, prob.values, x).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text and "all-gather" not in text
+    assert compiled.out_info.shape == () and compiled.out_info.dtype == jnp.float32
