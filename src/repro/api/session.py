@@ -53,6 +53,7 @@ from repro.core.engine import (
 )
 from repro.core.distributed import HybridDriver
 from repro.core.teams import global_problem
+from repro.kernels.ell_margins import margin_layout
 from repro.obs import trace as obs_trace
 from repro.train.checkpoint import (
     SessionCheckpoint,
@@ -181,6 +182,9 @@ class Session:
             self._driver = None
             self._x = jnp.asarray(x0)
             self._gp = global_problem(self.bundle.team)
+            # the loss probe's kernel layout of the same matrix (on a TPU)
+            margins = margin_layout(self._gp.ya.indices, self._gp.ya.values, n)
+            self._margins = None if margins is None else jax.device_put(margins)
             # the counted-comm ledger: the round body's collectives,
             # captured abstractly from the problem actually built
             self.ledger = engine_comm_ledger(
@@ -198,6 +202,7 @@ class Session:
             )
             self._x = None
             self._gp = None
+            self._margins = self._driver.margins
             self.ledger = self._driver.ledger  # driver commits rounds
 
     # ---- state probes ----
@@ -301,13 +306,16 @@ class Session:
     def _sample_loss(self) -> float:
         """The full objective at the current iterate: on the mesh over
         the placed shards (``path="mesh"``), else over the team problem
-        on the device (``path="device"``). Either way the one transfer
-        to the host is the float32 scalar."""
+        on the device (``path="device"``); its margins from the
+        ``ell_margins`` kernel (``margins="kernel"``, on a TPU) or from
+        ``ell_matvec``'s gather (``margins="gather"``). Either way the
+        one transfer to the host is the float32 scalar."""
         path = "device" if self._driver is None else "mesh"
-        with obs_trace.span("probe", path=path):
+        margins = "gather" if self._margins is None else "kernel"
+        with obs_trace.span("probe", path=path, margins=margins):
             if self._driver is not None:
                 return self._driver.loss()
-            return float(engine_loss(self._gp, self._x))
+            return float(engine_loss(self._gp, self._x, self._margins))
 
     def _copy_x(self) -> np.ndarray:
         """``current_x()`` through the tracing seam: the host copy of the
@@ -616,7 +624,7 @@ class Session:
         if self._driver is not None:
             final_loss = self._driver.loss()
         else:
-            final_loss = float(engine_loss(self._gp, self._x))
+            final_loss = float(engine_loss(self._gp, self._x, self._margins))
         return RunReport(
             spec=self.spec,
             plan=self._plan,

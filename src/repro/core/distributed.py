@@ -54,6 +54,7 @@ from repro.core.engine import (
     wire_gv,
 )
 from repro.core.objective import LOGISTIC, Objective, get_objective
+from repro.kernels.ell_margins import MarginLayout, ell_margins, margin_layout
 from repro.sparse.csr import CSRMatrix
 from repro.sparse.ell import EllBlock, ell_matvec, ell_rmatvec
 from repro.sparse.partition import ColumnPartition, partition_columns, partition_rows
@@ -377,13 +378,18 @@ def make_hybrid_step(
 
 
 def make_hybrid_loss(mesh: Mesh, prob: Hybrid2DProblem):
-    """Return a jitted fn (indices, values, x_pad) → f(x), the full
-    objective over the blocks as ``HybridDriver`` places them, computed
-    where they lie: each device takes its rows' partial margins over its
-    column shard, the partials are summed over "cols", each team sums
-    the losses of its valid rows, and the teams' sums are summed over
-    "rows". The l2 term sums each column shard's slice once (x is
-    replicated across "rows"). Only the scalar result leaves the mesh.
+    """Return a jitted fn (indices, values, x_pad, margins=None) → f(x),
+    the full objective over the blocks as ``HybridDriver`` places them,
+    computed where they lie: each device takes its rows' partial margins
+    over its column shard, the partials are summed over "cols", each
+    team sums the losses of its valid rows, and the teams' sums are
+    summed over "rows". The l2 term sums each column shard's slice once
+    (x is replicated across "rows"). Only the scalar result leaves the
+    mesh.
+
+    The partial margins come from the ``ell_margins`` kernel over each
+    block's own layout where ``margins`` (``margin_layout`` of the
+    blocks, placed like them) is given, else from ``ell_matvec``.
 
     A team's rows past its true count are zero padding (margin 0, loss
     ℓ(0), not 0), so they are masked by the same ``partition_rows``
@@ -391,10 +397,14 @@ def make_hybrid_loss(mesh: Mesh, prob: Hybrid2DProblem):
     counts = np.diff(partition_rows(prob.m, prob.p_r)).tolist()
     objective, n_loc, m = prob.objective, prob.n_loc, prob.m
 
-    def loss_fn(idx_blk, val_blk, x_loc):
+    def loss_fn(idx_blk, val_blk, x_loc, margins_blk):
         with jax.named_scope("loss_probe"):
-            blk = EllBlock(indices=idx_blk[0, 0], values=val_blk[0, 0], n=n_loc)
-            margin = jax.lax.psum(ell_matvec(blk, x_loc), "cols")
+            if margins_blk is None:
+                blk = EllBlock(indices=idx_blk[0, 0], values=val_blk[0, 0], n=n_loc)
+                partial = ell_matvec(blk, x_loc)
+            else:
+                partial = ell_margins(jax.tree.map(lambda a: a[0, 0], margins_blk), x_loc)
+            margin = jax.lax.psum(partial, "cols")
             losses = objective.pointwise_loss(margin)
             team = jax.lax.axis_index("rows")
             valid = sum(jnp.where(team == i, c, 0) for i, c in enumerate(counts))
@@ -404,10 +414,15 @@ def make_hybrid_loss(mesh: Mesh, prob: Hybrid2DProblem):
                 f = f + 0.5 * objective.l2 * jax.lax.psum(jnp.sum(x_loc * x_loc), "cols")
             return f
 
-    return jax.jit(shard_map(
-        loss_fn, mesh=mesh, in_specs=(P("rows", "cols"), P("rows", "cols"), P("cols")),
-        out_specs=P(),
-    ))
+    blocks = P("rows", "cols")
+    probe = shard_map(loss_fn, mesh=mesh, in_specs=(blocks, blocks, P("cols"), blocks),
+                      out_specs=P())
+
+    @jax.jit
+    def loss(indices, values, x_pad, margins: MarginLayout | None = None):
+        return probe(indices, values, x_pad, margins)
+
+    return loss
 
 
 class HybridDriver:
@@ -459,6 +474,10 @@ class HybridDriver:
         self._x_sh = NamedSharding(mesh, P("cols"))
         self._idx = jax.device_put(prob.indices, data_sh)
         self._val = jax.device_put(prob.values, data_sh)
+        # each block's layout for the loss probe's kernel (on a TPU),
+        # built on the host and placed like the blocks
+        margins = margin_layout(prob.indices, prob.values, prob.n_loc)
+        self.margins = None if margins is None else jax.device_put(margins, data_sh)
         self._x_pad = jax.device_put(
             jnp.asarray(scatter_x(np.asarray(x0), cp, prob.n_loc)), self._x_sh
         )
@@ -578,7 +597,7 @@ class HybridDriver:
         """Full global objective (under the problem's objective) at the
         current iterate, computed on the mesh over the placed blocks
         (``make_hybrid_loss``); only the scalar comes to the host."""
-        return float(self._loss(self._idx, self._val, self._x_pad))
+        return float(self._loss(self._idx, self._val, self._x_pad, self.margins))
 
 
 def run_hybrid_distributed(

@@ -60,9 +60,10 @@ import jax.numpy as jnp
 from repro.core import comm as comm_plane
 from repro.core.comm import COUNTING, CommLedger
 from repro.core.objective import LOGISTIC, Objective
-from repro.core.problem import Problem, problem_loss
+from repro.core.problem import Problem, margin_loss, problem_loss
 from repro.core.teams import TeamProblem, global_problem
 from repro.kernels.ell_gram import ell_gram_and_v, ell_gram_and_v_blocked
+from repro.kernels.ell_margins import MarginLayout, ell_margins
 from repro.kernels.ref import ell_gram_and_v_ref
 from repro.sparse.ell import EllBlock, ell_matvec, ell_rmatvec
 
@@ -604,11 +605,16 @@ def lower_engine_chunk(tp: TeamProblem, x: jnp.ndarray, k: int,
 
 
 @jax.jit
-def engine_loss(gp, x):
+def engine_loss(gp, x, margins: MarginLayout | None = None):
     """The session's loss probe — same ``problem_loss`` (under ``gp``'s
-    objective) the monolithic scan samples at chunk boundaries."""
+    objective) the monolithic scan samples at chunk boundaries. With
+    ``margins``, a layout of ``gp``'s matrix (``margin_layout``, built on
+    a TPU), the margins come from the ``ell_margins`` kernel instead of
+    the gather in ``ell_matvec``."""
     with jax.named_scope("loss_probe"):
-        return problem_loss(gp, x)
+        if margins is None:
+            return problem_loss(gp, x)
+        return margin_loss(gp, ell_margins(margins, x), x)
 
 
 def run_engine_chunk(

@@ -88,7 +88,11 @@ def problem_loss(problem: Problem, x: jnp.ndarray) -> jnp.ndarray:
     sign is folded into ya = diag(y)A."""
     from repro.sparse.ell import ell_matvec
 
-    margin = ell_matvec(problem.ya, x)
+    return margin_loss(problem, ell_matvec(problem.ya, x), x)
+
+
+def margin_loss(problem: Problem, margin: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """``problem_loss`` from the margins ya·x however they were computed."""
     losses = problem.objective.pointwise_loss(margin)
     losses = jnp.where(problem.rows_valid, losses, 0.0)
     f = jnp.sum(losses) / problem.m

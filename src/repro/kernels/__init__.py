@@ -5,6 +5,9 @@
                mkl_sparse_syrkd hot spot of Algorithm 3)
   sstep_inner — the s-step correction loop fused into one launch
                (G, v, u stay VMEM-resident across all s steps)
+  ell_margins — the loss probe's margins A·x over a layout of the
+               static matrix whose shapes follow from its shapes alone
+               (x gathered in registers, margins held in VMEM)
 
 ref.py: pure-jnp oracles — including the retired (sb × n) densify
 bundle path, kept only as the parity oracle. The pre-engine dense-panel

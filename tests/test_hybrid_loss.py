@@ -41,15 +41,20 @@ CHILD = textwrap.dedent("""
     from repro.core.distributed import HybridDriver, build_2d_problem, run_hybrid_distributed
     from repro.core.objective import get_objective
     from repro.core.problem import make_problem, problem_loss
+    from repro.kernels.ell_margins import build_margin_layout
     from repro.sparse.synthetic import make_skewed_csr
 
-    out = {"cases": {}}
+    out = {"cases": {}, "kernel": {}}
     rng = np.random.default_rng(0)
     m, n, rm = 250, 100, 8  # 250 rows: every team count leaves pad rows at 8
     A = make_skewed_csr(m, n, 12, 0.8, seed=3)
     y = np.where(rng.random(m) < 0.5, 1.0, -1.0)
     x = (0.3 * rng.standard_normal(n)).astype(np.float32)
     sched = ParallelSGDSchedule.hybrid(1, 2, 4, 0.05, 8, rounds=1)
+    m2, n2 = 600, 5_000
+    A2 = make_skewed_csr(m2, n2, 12, 0.8, seed=5)
+    y2 = np.where(rng.random(m2) < 0.5, 1.0, -1.0)
+    x2 = (0.3 * rng.standard_normal(n2)).astype(np.float32)
     for p_r, p_c in MESHES:
         mesh = compat.make_mesh((p_r, p_c), ("rows", "cols"))
         for part in PARTITIONERS:
@@ -60,7 +65,21 @@ CHILD = textwrap.dedent("""
                 drv = HybridDriver(mesh, prob, cp, x, dataclasses.replace(sched, p_r=p_r))
                 ref = float(problem_loss(make_problem(A, y, row_multiple=rm, objective=obj),
                                          jnp.asarray(x)))
-                out["cases"][f"{p_r}x{p_c}-{part}-{name}-{l2}"] = [drv.loss(), ref]
+                key = f"{p_r}x{p_c}-{part}-{name}-{l2}"
+                out["cases"][key] = [drv.loss(), ref]
+                # the probe with its margins from the kernel (the
+                # interpreter here) over each block's own layout, on a
+                # matrix whose every block has two row tiles or more and
+                # two column windows or more
+                prob, cp = build_2d_problem(A2, y2, p_r, p_c, part, row_multiple=rm,
+                                            objective=obj)
+                drv = HybridDriver(mesh, prob, cp, x2, dataclasses.replace(sched, p_r=p_r))
+                lay = jax.device_put(build_margin_layout(prob.indices, prob.values, prob.n_loc),
+                                     drv._data_sh)
+                ref = float(problem_loss(make_problem(A2, y2, row_multiple=rm, objective=obj),
+                                         jnp.asarray(x2)))
+                out["kernel"][key] = [float(drv._loss(drv._idx, drv._val, drv._x_pad, lay)),
+                                      ref]
 
     # the probe after its first call: no upload, under the guard
     # ("disallow_explicit" also refuses device_put / jnp.asarray of a
@@ -131,6 +150,19 @@ def test_mesh_loss_matches_problem_loss(child, mesh, partitioner, objective):
     """The mesh loss against ``problem_loss`` on the uncut problem, at
     random weights, with pad rows in every team and pad slots in x."""
     got, ref = child["cases"][f"{mesh[0]}x{mesh[1]}-{partitioner}-{objective[0]}-{objective[1]}"]
+    assert got == pytest.approx(ref, rel=RTOL)
+
+
+@pytest.mark.parametrize("objective", OBJECTIVES, ids=lambda o: f"{o[0]}-l2={o[1]}")
+@pytest.mark.parametrize("partitioner", PARTITIONERS)
+@pytest.mark.parametrize("mesh", MESHES, ids=lambda s: f"{s[0]}x{s[1]}")
+def test_mesh_kernel_loss_matches_problem_loss(child, mesh, partitioner, objective):
+    """The mesh loss with each block's partial margins from the
+    ``ell_margins`` kernel (as on a TPU) against ``problem_loss``, on a
+    600 x 5,000 matrix: every block spans two row tiles or more and two
+    column windows or more. The kernel sums a row's products in its own
+    order, which the same RTOL covers."""
+    got, ref = child["kernel"][f"{mesh[0]}x{mesh[1]}-{partitioner}-{objective[0]}-{objective[1]}"]
     assert got == pytest.approx(ref, rel=RTOL)
 
 

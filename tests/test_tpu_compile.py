@@ -205,3 +205,53 @@ def test_mesh_loss_compiles_to_a_replicated_scalar(rcv1_2x2):
     text = compiled.as_text()
     assert "all-reduce" in text and "all-gather" not in text
     assert compiled.out_info.shape == () and compiled.out_info.dtype == jnp.float32
+
+
+def _layout_sds(rows, n, width, lead, sharding):
+    from repro.kernels.ell_margins import Geometry, MarginLayout
+
+    geo = Geometry(rows, n, width)
+    return MarginLayout(
+        meta=_sds((*lead, geo.lane_rows, 128), jnp.int32, sharding),
+        values=_sds((*lead, geo.lane_rows, 128), jnp.float32, sharding),
+        places=_sds((*lead, geo.lane_rows), jnp.int32, sharding),
+        chunks=_sds((*lead, 1), jnp.int32, sharding),
+        rows=rows, n=n, width=width,
+    )
+
+
+def test_news20_probe_compiles_with_the_margin_kernel(one_chip, monkeypatch):
+    """The news20 loss probe (19,996 rows of width 481 over n =
+    1,355,191) with its margins from the kernel: no XLA gather is left."""
+    import repro.kernels.ell_margins as ell_margins
+    from repro.core.engine import engine_loss
+    from repro.core.problem import Problem
+    from repro.sparse.ell import EllBlock
+
+    monkeypatch.setattr(ell_margins, "default_interpret", lambda: False)
+    rows, n, width = 19_996, 1_355_191, 481
+    gp = Problem(
+        ya=EllBlock(_sds((rows, width), jnp.int32, one_chip),
+                    _sds((rows, width), jnp.float32, one_chip), n),
+        rows_valid=_sds((rows,), jnp.bool_, one_chip), m=rows, n=n,
+    )
+    margins = _layout_sds(rows, n, width, (), one_chip)
+    text = engine_loss.lower(gp, _sds((n,), jnp.float32, one_chip), margins).compile().as_text()
+    assert "tpu_custom_call" in text and "ell_margins" in text
+    assert " gather(" not in text
+
+
+def test_mesh_loss_compiles_with_the_margin_kernel(rcv1_2x2, monkeypatch):
+    """The mesh probe over each 10,176 x 64 block's layout (n_loc =
+    23,618), inside shard_map: one f32 scalar out, no gather."""
+    import repro.kernels.ell_margins as ell_margins
+    from repro.core.distributed import make_hybrid_loss
+
+    monkeypatch.setattr(ell_margins, "default_interpret", lambda: False)
+    mesh, prob, x = rcv1_2x2
+    margins = _layout_sds(10_176, 23_618, 64, (2, 2), NamedSharding(mesh, P("rows", "cols")))
+    compiled = make_hybrid_loss(mesh, prob).lower(prob.indices, prob.values, x, margins).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+    assert " gather(" not in text and "all-gather" not in text
+    assert compiled.out_info.shape == () and compiled.out_info.dtype == jnp.float32
