@@ -1,13 +1,13 @@
 """The paper's contribution: one 2D-parallel SGD family, in JAX.
 
 The unified engine (repro.core.engine) implements the whole
-(p_r, p_c, s, τ) family with one inner loop on the scatter-free Pallas
-Gram path:
+(p_r, p_c, s, τ) family with one round body on the scatter-free Pallas
+Gram path, which both backends run:
 
   run_parallel_sgd     the engine — any point of the family
   ParallelSGDSchedule  the knob object (corners by name: mb_sgd,
                        sstep, fedavg, hybrid)
-  bundle_gram_v        the shared s-bundle primitive (G, v)
+  bundle_gram_v        the s-bundle primitive (G, v)
 
 Configured corners, kept as thin wrappers for compatibility:
 
@@ -16,8 +16,8 @@ Configured corners, kept as thin wrappers for compatibility:
   run_fedavg           Algorithm 2 — FedAvg / local SGD
   run_hybrid_sgd       HybridSGD, exact simulated-rank semantics
   run_hybrid_distributed  HybridSGD under shard_map on a 2D device mesh
-                          (consumes the same ParallelSGDSchedule and
-                          shares the engine's bundle primitive)
+                          (consumes the same ParallelSGDSchedule; each
+                          rank runs the engine's round body)
   HybridDriver         the round-incremental form of the same executor
                        (device-resident carry; advance k rounds at a
                        time — what repro.api.Session drives)

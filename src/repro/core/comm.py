@@ -406,29 +406,23 @@ class Collectives:
 
     # ---- the row-team (Gram) Allreduce: sum over column shards ----
 
-    def allreduce_cols(self, tree, *, calls_per_round: int = 1,
-                       words_per_call: int | None = None):
+    def allreduce_cols(self, tree, *, calls_per_round: int = 1):
         """Sum ``tree`` across the "cols" mesh axis (the per-bundle
-        (G, v) Allreduce — Table 3's row-team payload).
+        (G, v) Allreduce — Table 3's row-team payload, counted from the
+        traced leaf shapes).
 
         counting: identity — the simulated ranks compute the full (G, v)
         directly, so the reduced value is already in hand. mesh/timed:
         one ``psum`` per leaf (separate binds, exactly the two psum
         calls the pre-layer code issued — bitwise-identical HLO).
-
-        ``words_per_call`` overrides the payload derived from the traced
-        leaf shapes — the s = 1 engine corner uses it to account the
-        full (G, v) payload its distributed twin puts on the wire even
-        though the simulated body only materializes v.
         """
         rec = _RECORDER.get()
         if rec is not None:
-            words = words_per_call if words_per_call is not None else _tree_words(tree)
             rec.rates.append(CommRate(
                 op="allreduce",
                 axis="cols",
                 span=rec.spans.get("cols", 1),
-                words_per_call=int(words),
+                words_per_call=_tree_words(tree),
                 calls_per_round=int(calls_per_round),
                 word_bytes=_tree_word_bytes(tree),
             ))
@@ -437,50 +431,20 @@ class Collectives:
             return tree
         return jax.tree_util.tree_map(lambda t: jax.lax.psum(t, "cols"), tree)
 
-    # ---- the async-dispatch-shaped split of the Gram Allreduce ----
-    #
-    # JAX collectives are dispatched asynchronously: the Python call
-    # returns a future-backed array and the host only blocks when a
-    # value is needed. The delay-D pipeline makes that explicit at the
-    # call-site level — ``issue_allreduce_cols`` at bundle k starts the
-    # reduction, ``await_allreduce`` at bundle k+D marks where its value
-    # is first consumed. Under XLA the issue *is* the psum (recorded
-    # once, same payload accounting as the fused call) and the await is
-    # the identity: the D bundle-computes the scheduler runs in between
-    # are what actually hides the transfer.
-
-    def issue_allreduce_cols(self, tree, *, calls_per_round: int = 1,
-                             words_per_call: int | None = None):
-        """Start the per-bundle (G, v) Allreduce for a delayed schedule.
-        Same reduction, recording, and payload conventions as
-        ``allreduce_cols`` — the split exists so traces and ledgers can
-        attribute the in-flight window."""
-        return self.allreduce_cols(
-            tree, calls_per_round=calls_per_round, words_per_call=words_per_call
-        )
-
-    def await_allreduce(self, tree):
-        """Consume a previously issued Allreduce. Identity on every
-        kind and never recorded — the payload was counted at issue
-        time; this marks the critical-path join point."""
-        return tree
-
     # ---- the column Allreduce: average weights across row teams ----
 
-    def allmean_rows(self, x, *, calls_per_round: int = 1,
-                     words_per_call: int | None = None):
+    def allmean_rows(self, x, *, calls_per_round: int = 1):
         """Average the per-shard weight slab across the "rows" mesh axis
         (the per-τ-iterations FedAvg sync — Table 3's column payload).
         Mesh/timed only; the simulated engine's stacked form is
         ``allmean_teams``."""
         rec = _RECORDER.get()
         if rec is not None:
-            words = words_per_call if words_per_call is not None else _tree_words(x)
             rec.rates.append(CommRate(
                 op="allmean",
                 axis="rows",
                 span=rec.spans.get("rows", 1),
-                words_per_call=int(words),
+                words_per_call=_tree_words(x),
                 calls_per_round=int(calls_per_round),
                 word_bytes=_tree_word_bytes(x),
             ))

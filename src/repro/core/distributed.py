@@ -8,10 +8,10 @@ axes are ("rows", "cols") = (p_r, p_c):
   plus its n_loc-word shard of the weight vector.
 
 Per s-bundle (the paper's row-team Allreduce):
-  G_partial, v_partial computed locally via the engine's shared bundle
-  primitive (repro.core.engine.bundle_gram_v — scatter-free) → psum
-  over "cols" (exactly the (s²b² + sb)-word payload of Table 3); the
-  weight update Yᵀu is fully local under column partitioning.
+  G_partial, v_partial computed locally by the engine's round body
+  (repro.core.engine.round_body, run per rank on the column shard) →
+  psum over "cols" (exactly the (s²b² + sb)-word payload of Table 3);
+  the weight update Yᵀu is fully local under column partitioning.
 Per τ inner iterations (the paper's column Allreduce):
   x_local ← pmean over "rows" (n/p_c words per rank).
 
@@ -22,11 +22,11 @@ the round body's exact spans and payloads into a ``CommLedger``, and
 into it as it advances.
 
 The execution knobs arrive as one ``ParallelSGDSchedule`` — the same
-object the simulated engine consumes — so the two paths cannot drift on
-plumbing. The legacy loose-scalar signatures (s=..., b=..., ...) are
-kept as deprecated shims.
+object the simulated engine consumes — and each rank runs the engine's
+own round body, so this module holds only what is the mesh's: the
+layout, placement, the shard_map step and its driver.
 
-Numerics match repro.core.engine.run_parallel_sgd exactly (tested in a
+Numerics match repro.core.engine.run_parallel_sgd (tested in a
 multi-device subprocess); the simulated version is the oracle.
 """
 
@@ -34,7 +34,6 @@ from __future__ import annotations
 
 import dataclasses
 import time
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -44,19 +43,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.compat import shard_map
 from repro.core import comm as comm_plane
 from repro.core.comm import MESH, Collectives, CommLedger
-from repro.core.engine import (
-    ParallelSGDSchedule,
-    bundle_gram_v,
-    check_delay,
-    delayed_bundle_scan,
-    inner_corrections,
-    unwire_gv,
-    wire_gv,
-)
+from repro.core.engine import ParallelSGDSchedule, bundle_gram_v, check_delay, round_body
 from repro.core.objective import LOGISTIC, Objective, get_objective
 from repro.kernels.ell_margins import MarginLayout, ell_margins, margin_layout
 from repro.sparse.csr import CSRMatrix
-from repro.sparse.ell import EllBlock, ell_matvec, ell_rmatvec
+from repro.sparse.ell import EllBlock, ell_matvec
 from repro.sparse.partition import ColumnPartition, partition_columns, partition_rows
 
 
@@ -167,116 +158,24 @@ def gather_x(x_pad: np.ndarray, cp: ColumnPartition, n_loc: int, n: int) -> np.n
     return out
 
 
-def _legacy_schedule(
-    p_r: int, s, b, eta, tau, rounds, gram: str, caller: str
-) -> ParallelSGDSchedule:
-    """Adapt the pre-API loose-scalar knobs into a schedule (deprecated)."""
-    warnings.warn(
-        f"{caller}(s=..., b=..., tau=..., ...) with loose scalars is deprecated; "
-        f"pass a repro.core.ParallelSGDSchedule (or use the repro.api front door)",
-        DeprecationWarning,
-        stacklevel=3,
-    )
-    if b is None or eta is None or tau is None:
-        raise TypeError(f"legacy {caller} call is missing one of (b, eta, tau)")
-    return ParallelSGDSchedule.hybrid(
-        p_r, int(s), int(b), float(eta), int(tau),
-        rounds=int(rounds) if rounds is not None else 1, gram=gram or "blocked",
-    )
-
-
-def _reject_scalars_with_schedule(caller: str, **scalars) -> None:
-    """A schedule is the whole configuration — a scalar knob alongside
-    it would be silently ignored, so make that a hard error."""
-    extras = [k for k, v in scalars.items() if v is not None]
-    if extras:
-        raise TypeError(
-            f"{caller}: got both a ParallelSGDSchedule and scalar knob(s) "
-            f"{extras} — the schedule carries all knobs; use "
-            f"dataclasses.replace(sched, ...) instead"
-        )
-
-
 def _build_round_fn(prob: Hybrid2DProblem, sched: ParallelSGDSchedule,
                     comm: Collectives = MESH):
-    """The per-rank round body (what shard_map maps): τ inner s-step
-    iterations + the column average, all communication issued through
-    the ``comm`` collectives. Shared by ``make_hybrid_step`` (which
-    shard_maps and jits it) and ``hybrid_comm_ledger`` (which captures
-    it abstractly) — one function, so the ledger cannot drift from the
-    executed collectives."""
-    s, b_, eta_ = sched.s, sched.b, sched.eta
-    sb = s * b_
-    n_loc = prob.n_loc
-    bundles = sched.tau // s
-    objective = prob.objective
-    lam = objective.l2
+    """The per-rank round body (what shard_map maps): the engine's
+    ``round_body`` on this rank's block and weight shard, then the
+    column average, all communication issued through the ``comm``
+    collectives. Shared by ``make_hybrid_step`` (which shard_maps and
+    jits it) and ``hybrid_comm_ledger`` (which captures it abstractly)
+    — one function, so the ledger cannot drift from the executed
+    collectives."""
 
     def round_fn(idx_blk, val_blk, x_loc, round_idx):
         # shapes inside shard_map: idx/val (1, 1, rows_local, width),
-        # x_loc (n_loc,)
-        idx_blk = idx_blk[0, 0]
-        val_blk = val_blk[0, 0]
-        m_local = idx_blk.shape[0]
-
-        if sched.delay:
-            # Delay-D pipeline: the per-bundle psum is *issued* at
-            # bundle t and first *consumed* at bundle t+D, so XLA's
-            # async dispatch has D bundle-computes of independent work
-            # to run while the reduction is in flight. The staging
-            # logic is the engine's shared scan — both backends execute
-            # the same pipelined math by construction.
-            def slice_bundle(t):
-                k0 = round_idx * bundles + t
-                start = (k0 * sb) % m_local
-                bi = jax.lax.dynamic_slice_in_dim(idx_blk, start, sb, axis=0)
-                bv = jax.lax.dynamic_slice_in_dim(val_blk, start, sb, axis=0)
-                return bi, bv
-
-            x_loc = delayed_bundle_scan(
-                x_loc, slice_bundle=slice_bundle, bundles=bundles, n=n_loc,
-                sched=sched, eta=eta_, objective=objective, comm=comm,
-            )
-            with jax.named_scope("param_avg"):
-                return comm.allmean_rows(x_loc)
-
-        def bundle(x_loc, t):
-            k0 = round_idx * bundles + t
-            start = (k0 * sb) % m_local
-            bi = jax.lax.dynamic_slice_in_dim(idx_blk, start, sb, axis=0)
-            bv = jax.lax.dynamic_slice_in_dim(val_blk, start, sb, axis=0)
-            # local partial (G, v) via the engine's shared primitive —
-            # then the row-team Allreduce (paper Table 3 payload; bf16
-            # words under the precision knob — the psum sums narrow
-            # payloads, corrections run on the f32 upcast)
-            with jax.named_scope("bundle_gram"):
-                g_part, v_part = bundle_gram_v(
-                    bi, bv, x_loc, n_loc, gram=sched.gram, bk=sched.bk, bm=sched.bm,
-                    precision=sched.precision,
-                )
-            with jax.named_scope("allreduce_gv"):
-                g, v = comm.allreduce_cols(
-                    wire_gv((g_part, v_part), sched.precision),
-                    calls_per_round=bundles,
-                )
-                g, v = unwire_gv((g, v), sched.precision)
-            with jax.named_scope("corrections"):
-                u = inner_corrections(g, v, s, b_, eta_, objective)
-            # Yᵀu stays local under column partitioning
-            blk = EllBlock(indices=bi, values=bv, n=n_loc)
-            with jax.named_scope("update"):
-                if lam == 0.0:
-                    return x_loc + (eta_ / b_) * ell_rmatvec(blk, u).astype(x_loc.dtype), None
-                # decay-folded update, exact under column sharding: the
-                # L2 decay is elementwise, so each shard decays its own
-                # slice (padded slots stay zero: ρ·0 + 0).
-                rho_s = jnp.asarray(1.0 - eta_ * lam, x_loc.dtype) ** s
-                return (
-                    rho_s * x_loc + (eta_ / b_) * ell_rmatvec(blk, u).astype(x_loc.dtype),
-                    None,
-                )
-
-        x_loc, _ = jax.lax.scan(bundle, x_loc, jnp.arange(bundles))
+        # x_loc (n_loc,). Yᵀu stays local under column partitioning, and
+        # the L2 decay is elementwise, so each shard decays its own slice.
+        x_loc = round_body(
+            idx_blk[0, 0], val_blk[0, 0], x_loc, round_idx, n=prob.n_loc,
+            sched=sched, eta=sched.eta, objective=prob.objective, comm=comm,
+        )
         # column Allreduce: FedAvg averaging across row teams (n/p_c
         # words) — the result is row-replicated, so the out_spec can
         # drop the "rows" axis.
@@ -308,14 +207,8 @@ def hybrid_comm_ledger(prob: Hybrid2DProblem, sched: ParallelSGDSchedule,
 def make_hybrid_step(
     mesh: Mesh,
     prob: Hybrid2DProblem,
-    sched: ParallelSGDSchedule | int | None = None,
-    b: int | None = None,
-    tau: int | None = None,
-    eta: float | None = None,
-    gram: str | None = None,
-    bk: int | None = None,
+    sched: ParallelSGDSchedule,
     *,
-    s: int | None = None,
     comm: Collectives = MESH,
 ):
     """Return a jitted fn (indices, values, x_pad, round_idx) → x_pad
@@ -332,21 +225,7 @@ def make_hybrid_step(
     The returned step donates ``x_pad`` and pins its output to the
     ``P("cols")`` sharding of the input, so drivers can chain rounds
     without re-placing the weights (no per-round sync + copy).
-
-    The legacy signature ``make_hybrid_step(mesh, prob, s, b, tau, eta,
-    gram=..., bk=...)`` still works but emits a DeprecationWarning.
     """
-    if isinstance(sched, ParallelSGDSchedule):
-        _reject_scalars_with_schedule(
-            "make_hybrid_step", s=s, b=b, tau=tau, eta=eta, gram=gram, bk=bk
-        )
-    else:
-        s_val = sched if sched is not None else s
-        if s_val is None:
-            raise TypeError("make_hybrid_step needs a ParallelSGDSchedule (or legacy s=...)")
-        sched = _legacy_schedule(prob.p_r, s_val, b, eta, tau, None, gram, "make_hybrid_step")
-        if bk is not None:
-            sched = dataclasses.replace(sched, bk=bk)
     if sched.tau % sched.s:
         raise ValueError(f"tau={sched.tau} must be divisible by s={sched.s}")
     if tuple(mesh.axis_names) != ("rows", "cols"):
@@ -605,41 +484,16 @@ def run_hybrid_distributed(
     prob: Hybrid2DProblem,
     cp: ColumnPartition,
     x0: np.ndarray,
-    sched: ParallelSGDSchedule | int | None = None,
-    b: int | None = None,
-    eta: float | None = None,
-    tau: int | None = None,
-    rounds: int | None = None,
-    gram: str | None = None,
-    *,
-    s: int | None = None,
+    sched: ParallelSGDSchedule,
 ):
     """Driver: place data once, run ``sched.rounds`` rounds, gather x.
 
-    Now a thin loop over ``HybridDriver`` — one ``advance`` per
+    A thin loop over ``HybridDriver`` — one ``advance`` per
     loss-sampling chunk. Returns ``(x, losses)`` — the same contract as
     the simulated engine's ``run_parallel_sgd``: the full global
     objective is sampled every ``sched.loss_every`` rounds (empty trace
     when 0), on the mesh by ``HybridDriver.loss``.
-
-    The legacy signature ``run_hybrid_distributed(mesh, prob, cp, x0,
-    s, b, eta, tau, rounds, gram=...)`` still works (returning bare
-    ``x``, its old contract) but emits a DeprecationWarning.
     """
-    legacy = not isinstance(sched, ParallelSGDSchedule)
-    if legacy:
-        s_val = sched if sched is not None else s
-        if s_val is None:
-            raise TypeError(
-                "run_hybrid_distributed needs a ParallelSGDSchedule (or legacy s=...)"
-            )
-        sched = _legacy_schedule(
-            prob.p_r, s_val, b, eta, tau, rounds, gram, "run_hybrid_distributed"
-        )
-    else:
-        _reject_scalars_with_schedule(
-            "run_hybrid_distributed", s=s, b=b, eta=eta, tau=tau, rounds=rounds, gram=gram
-        )
     driver = HybridDriver(mesh, prob, cp, x0, sched)
     losses = []
     chunk = sched.loss_every if sched.loss_every else sched.rounds
@@ -647,7 +501,4 @@ def run_hybrid_distributed(
         driver.advance(min(chunk, sched.rounds - driver.rounds_done))
         if sched.loss_every and driver.rounds_done % sched.loss_every == 0:
             losses.append(driver.loss())
-    x = driver.gather()
-    if legacy:
-        return x
-    return x, np.asarray(losses, dtype=np.float32)
+    return driver.gather(), np.asarray(losses, dtype=np.float32)

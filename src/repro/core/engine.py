@@ -15,10 +15,16 @@ between parameter averagings. One engine therefore subsumes them all:
 p_c is a *communication* knob, not a numerical one: it decides where
 columns live (and hence what is Allreduced — see
 repro.core.distributed), never what is computed. The engine here
-implements the exact simulated-rank semantics on one device; the
-shard_map execution in repro.core.distributed shares this module's
-bundle primitive and inner-correction loop, so the two paths cannot
-drift.
+implements the exact simulated-rank semantics on one device.
+
+``round_body`` is the one round body of both backends: τ/s s-bundles
+on one row team, each sliced cyclically, reduced through a
+``repro.core.comm`` collective, corrected and applied. The simulated
+engine runs it per team with the counting collectives (the identity on
+this backend's already-global values, recorded so
+``engine_comm_ledger`` captures what a run communicates); the shard_map
+executor in repro.core.distributed runs it per rank with the mesh ones.
+The delay-D pipeline is the same function at D ≥ 1.
 
 The s-bundle computation G = tril(Y Yᵀ, -1), v = Y x routes through the
 scatter-free Pallas ELL-Gram kernel (repro.kernels.ell_gram) — the old
@@ -27,23 +33,14 @@ parity oracle in repro.kernels.ref.
 
 The *loss* is pluggable (repro.core.objective): the engine reads the
 residual map u(z) = -ℓ′(z), the pointwise loss, and the optional L2
-decay from the problem's ``objective`` — the logistic default routes
-through bitwise the same computation as the pre-objective engine, and
-λ > 0 is exact via the decay-aware correction recurrence.
-
-*Communication* is explicit (repro.core.comm): the round body issues
-its two collectives — the per-bundle row-team (G, v) Allreduce and the
-per-round p_r-team average — through the counting collectives (the
-identity on this backend's already-global values), so
-``engine_comm_ledger`` can capture exactly what a run communicates and
-reports can place it next to the Eq. 4 model's predictions.
+decay from the problem's ``objective``; λ > 0 is exact via the
+decay-aware correction recurrence.
 
 The round body names its device work with ``jax.named_scope`` —
 ``bundle_gram``, ``allreduce_gv``, ``corrections``, ``update``,
-``param_avg``, and ``loss_probe`` in ``engine_loss`` — the same names
-as ``repro.core.distributed``'s, so a profile attributes each op to its
-phase on either backend. Scopes are metadata only: the iterates are
-unchanged.
+``param_avg``, and ``loss_probe`` in ``engine_loss`` — on either
+backend, so a profile attributes each op to its phase. Scopes are
+metadata only: the iterates are unchanged.
 
 repro.core.{sgd,sstep,fedavg,hybrid} re-export configured engine calls
 for backwards compatibility.
@@ -308,213 +305,132 @@ def inner_corrections(
     return u
 
 
-def delayed_bundle_scan(x, *, slice_bundle, bundles: int, n: int,
-                        sched: ParallelSGDSchedule, eta,
-                        objective: Objective = LOGISTIC,
-                        comm=COUNTING):
-    """The delay-D software pipeline over one round's τ/s bundles —
-    the shared round-body core of both backends when ``sched.delay ≥ 1``
-    (DaSGD, arXiv:2006.00441).
+def round_body(indices, values, x, round_idx, *, n: int,
+               sched: ParallelSGDSchedule, eta,
+               objective: Objective = LOGISTIC, comm=COUNTING):
+    """One round of one row team: τ/s s-bundles over its ELL rows
+    (``indices``/``values``, (rows, width), column ids in [0, n)) from
+    the iterate ``x``. The single round body of both backends: the
+    simulated engine maps it over its stacked teams with ``COUNTING``
+    collectives (identity — its (G, v) is already global), the shard_map
+    executor runs it per rank on the local column shard with ``MESH`` or
+    ``TIMED`` (a psum over "cols"). Parameter averaging is the caller's.
 
-    At step t the body computes bundle t's local (G, v) at the current
-    (D-bundle-stale) iterate and *issues* its row-team Allreduce
-    (``comm.issue_allreduce_cols``); the staged result rides a D-deep
-    FIFO in the scan carry and is *consumed* (``comm.await_allreduce``
-    → corrections → weight update) at step t+D — so on a mesh the
-    in-flight psum has the next D bundles' Gram compute to hide behind
-    (the data dependency lands D iterations later, which is the window
-    XLA's scheduler overlaps). After the main scan the last D staged
-    entries drain synchronously, *before* the caller's parameter
-    average: every round boundary carries only ``x``, so chunking,
-    checkpointing, and the τ-cadence averaging are exactly where the
-    synchronous schedule puts them.
+    Each bundle t of round r is the cyclic sample of s·b rows at
+    ((r·τ/s + t)·s·b) mod rows (§5). It runs (G, v) = (tril(YYᵀ,-1), Yx)
+    → ``comm.allreduce_cols`` → ``inner_corrections`` → the weight
+    update x ← ρ^s·x + (η/b)·Yᵀu (ρ = 1 - ηλ; ρ^s = 1 without L2).
+    ``eta`` may be traced (the engine: no recompile per value) or a
+    Python float (the mesh step). At s = 1 the Gram carries no
+    correction: v is one SpMV, G rides the collective as zeros (the
+    b² + b words of the Table 3 payload) and u is the residual of v.
 
-    Warmup steps (t < D) consume the zero-initialized buffer and are
-    masked out with ``jnp.where`` rather than ``lax.cond`` — no
-    collectives inside conditionals (shard_map-safe), deterministic
-    wasted work on D dummy entries per round. Exactly ``bundles``
-    updates (and, under L2, exactly ``bundles`` decay folds) are
-    applied per round, same as the synchronous path.
-
-    At D = 0 the FIFO is empty and each bundle is consumed as it is
-    issued: the synchronous order, through this same code.
-
-    ``slice_bundle(t) -> (idx, val)`` supplies the (s·b, width) ELL
-    bundle; ``comm`` is COUNTING on the simulated engine (identity —
-    the staged value is already globally reduced) and MESH/TIMED under
-    shard_map."""
+    With ``sched.delay = D ≥ 1`` this is the DaSGD pipeline
+    (arXiv:2006.00441): bundle t's (G, v) is reduced at t, staged in a
+    D-deep FIFO in the scan carry and consumed at t + D, so on a mesh
+    the in-flight psum has the next D bundles' Gram compute to hide
+    behind. Warm-up steps (t < D) consume the zero-initialized buffer
+    and are masked out with ``jnp.where`` rather than ``lax.cond`` (no
+    collectives inside conditionals); the last D staged entries drain
+    before returning, so every round boundary carries only ``x`` and
+    exactly τ/s updates (and decay folds) are applied per round."""
     s, b = sched.s, sched.b
     sb = s * b
+    bundles = sched.tau // s
     d = sched.delay
     lam = objective.l2
 
-    def compute_issue(x, t):
-        idx, val = slice_bundle(t)
+    def compute_reduce(x, t):
+        start = ((round_idx * bundles + t) * sb) % indices.shape[0]
+        idx = jax.lax.dynamic_slice_in_dim(indices, start, sb, axis=0)
+        val = jax.lax.dynamic_slice_in_dim(values, start, sb, axis=0)
         with jax.named_scope("bundle_gram"):
-            g, v = bundle_gram_v(idx, val, x, n, gram=sched.gram, bk=sched.bk,
-                                 bm=sched.bm, precision=sched.precision)
-        # issued here, consumed D bundles later (the s = 1 corner
-        # stages the full (G, v) too — its distributed twin psums the
-        # dense block either way, so counted payloads stay pinned).
-        # Under bf16 the staged payload is the wire dtype: the FIFO
-        # holds exactly what the in-flight Allreduce carries.
+            if s == 1:
+                v = ell_matvec(EllBlock(indices=idx, values=val, n=n), x)
+                g = jnp.zeros((sb, sb), v.dtype)
+            else:
+                g, v = bundle_gram_v(idx, val, x, n, gram=sched.gram, bk=sched.bk,
+                                     bm=sched.bm, precision=sched.precision)
+        # under bf16 the collective (and the FIFO) carries the wire dtype
         with jax.named_scope("allreduce_gv"):
-            g, v = comm.issue_allreduce_cols(
+            g, v = comm.allreduce_cols(
                 wire_gv((g, v), sched.precision), calls_per_round=bundles
             )
         return idx, val, g, v
 
-    def consume_apply(x, entry, live):
+    def apply(x, entry):
         idx, val, g, v = entry
         with jax.named_scope("allreduce_gv"):
-            g, v = comm.await_allreduce((g, v))
-            g, v = unwire_gv((g, v), sched.precision)
+            g, v = unwire_gv((g, v), sched.precision, x.dtype)
         with jax.named_scope("corrections"):
-            u = inner_corrections(g, v, s, b, eta, objective)
+            if s == 1:
+                u = objective.residual(v)
+            else:
+                u = inner_corrections(g, v, s, b, eta, objective)
         with jax.named_scope("update"):
             blk = EllBlock(indices=idx, values=val, n=n)
             upd = (eta / b) * ell_rmatvec(blk, u).astype(x.dtype)
             if lam == 0.0:
-                return jnp.where(live, x + upd, x)
-            rho_s = jnp.asarray(1.0 - eta * lam, x.dtype) ** s
-            return jnp.where(live, rho_s * x + upd, x)
+                return x + upd
+            # inner_corrections returns the ρ-weighted u; ρ^0 = 1 at s = 1
+            return jnp.asarray(1.0 - eta * lam, x.dtype) ** s * x + upd
 
-    # the D-deep staging FIFO: buf[0] is the oldest in-flight bundle.
-    # Shapes/dtypes are written out by hand (an eval_shape through
-    # compute_issue would double-record the collective under the
-    # ledger's capture recorder).
-    idx0, val0 = slice_bundle(0)
-    width = idx0.shape[-1]
-    gv_dtype = jnp.result_type(val0.dtype, x.dtype)
+    if not d:
+        def step(x, t):
+            return apply(x, compute_reduce(x, t)), None
+
+        x, _ = jax.lax.scan(step, x, jnp.arange(bundles))
+        return x
+
+    # the D-deep FIFO (buf[0] the oldest). Shapes are written out by hand:
+    # an eval_shape through compute_reduce would record the collective
+    # twice under the ledger's capture.
+    gv_dtype = jnp.result_type(values.dtype, x.dtype)
     if sched.precision == "bf16":
-        gv_dtype = jnp.bfloat16  # the FIFO stages the wire payload
+        gv_dtype = jnp.bfloat16
     buf = (
-        jnp.zeros((d, sb, width), idx0.dtype),
-        jnp.zeros((d, sb, width), val0.dtype),
+        jnp.zeros((d, sb, indices.shape[-1]), indices.dtype),
+        jnp.zeros((d, sb, values.shape[-1]), values.dtype),
         jnp.zeros((d, sb, sb), gv_dtype),
         jnp.zeros((d, sb), gv_dtype),
     )
 
-    def body(carry, t):
+    def pipelined(carry, t):
         x, buf = carry
-        new = compute_issue(x, t)
-        if not d:  # the degenerate pipeline: each bundle consumed as issued
-            return (consume_apply(x, new, True), buf), None
+        new = compute_reduce(x, t)  # reduced at t, consumed at t + D
         oldest = jax.tree_util.tree_map(lambda a: a[0], buf)
         buf = jax.tree_util.tree_map(
             lambda a, e: jnp.concatenate([a[1:], e[None]], axis=0), buf, new
         )
-        x = consume_apply(x, oldest, t >= d)
-        return (x, buf), None
+        return (jnp.where(t >= d, apply(x, oldest), x), buf), None
 
-    (x, buf), _ = jax.lax.scan(body, (x, buf), jnp.arange(bundles))
+    (x, buf), _ = jax.lax.scan(pipelined, (x, buf), jnp.arange(bundles))
 
     def drain(x, j):
         entry = jax.tree_util.tree_map(
             lambda a: jax.lax.dynamic_index_in_dim(a, j, keepdims=False), buf
         )
-        return consume_apply(x, entry, jnp.bool_(True)), None
+        return apply(x, entry), None
 
-    if d:
-        x, _ = jax.lax.scan(drain, x, jnp.arange(d))
-    return x
-
-
-def _team_inner_iterations(indices, values, n: int, x, round_idx, eta,
-                           sched: ParallelSGDSchedule,
-                           objective: Objective = LOGISTIC):
-    """τ inner iterations (= τ/s s-bundles) on one row team's ELL rows.
-    ``eta`` is a traced scalar (sweep-friendly: no recompile per value);
-    ``objective`` supplies the residual and (when l2 > 0) the decay
-    fold — exact on every corner, since the s-bundle recurrence in
-    ``inner_corrections`` is decay-aware."""
-    m_local = indices.shape[0]
-    bundles = sched.tau // sched.s
-    s, b = sched.s, sched.b
-    sb = s * b
-    lam = objective.l2
-
-    if sched.delay:
-        def slice_bundle(t):
-            k0 = round_idx * bundles + t
-            start = (k0 * sb) % m_local
-            idx = jax.lax.dynamic_slice_in_dim(indices, start, sb, axis=0)
-            val = jax.lax.dynamic_slice_in_dim(values, start, sb, axis=0)
-            return idx, val
-
-        return delayed_bundle_scan(
-            x, slice_bundle=slice_bundle, bundles=bundles, n=n, sched=sched,
-            eta=eta, objective=objective, comm=COUNTING,
-        )
-
-    def bundle_step(x, t):
-        k0 = round_idx * bundles + t
-        start = (k0 * sb) % m_local
-        idx = jax.lax.dynamic_slice_in_dim(indices, start, sb, axis=0)
-        val = jax.lax.dynamic_slice_in_dim(values, start, sb, axis=0)
-        bundle = EllBlock(indices=idx, values=val, n=n)
-        if s == 1:
-            # FedAvg/MB-SGD corner: the Gram is empty (no deferred
-            # updates to correct) — one SpMV + one SpMVᵀ, exactly
-            # Algorithm 2's local step. The simulated body only
-            # materializes v = Yx, but the distributed corner psums the
-            # full (G, v) bundle even at s = 1 (G rides the wire though
-            # numerically unused), so the counted payload is pinned to
-            # the same sb² + sb words.
-            with jax.named_scope("bundle_gram"):
-                yx = ell_matvec(bundle, x)
-            with jax.named_scope("allreduce_gv"):
-                yx = COUNTING.allreduce_cols(
-                    wire_gv(yx, sched.precision),
-                    calls_per_round=bundles,
-                    words_per_call=sb * sb + sb,
-                )
-                yx = unwire_gv(yx, sched.precision, x.dtype)
-            with jax.named_scope("corrections"):
-                u = objective.residual(yx)
-        else:
-            with jax.named_scope("bundle_gram"):
-                g, v = bundle_gram_v(idx, val, x, n, gram=sched.gram, bk=sched.bk,
-                                     bm=sched.bm, precision=sched.precision)
-            # row-team Allreduce of the bundle (G, v) — identity here
-            # (the simulated rank computes the full reduction), the
-            # recorded payload when the round body is captured.
-            with jax.named_scope("allreduce_gv"):
-                g, v = COUNTING.allreduce_cols(
-                    wire_gv((g, v), sched.precision), calls_per_round=bundles
-                )
-                g, v = unwire_gv((g, v), sched.precision)
-            with jax.named_scope("corrections"):
-                u = inner_corrections(g, v, s, b, eta, objective)
-        with jax.named_scope("update"):
-            if lam == 0.0:
-                return x + (eta / b) * ell_rmatvec(bundle, u).astype(x.dtype), None
-            # decay-folded update: x_s = ρ^s·x + (η/b)·Yᵀ·[ρ^{s-1-l}·u_l]
-            # (inner_corrections already returns the ρ-weighted u; for
-            # s = 1 the weight is ρ^0 = 1). Exact on the s = 1 corners.
-            rho_s = jnp.asarray(1.0 - eta * lam, x.dtype) ** s
-            return rho_s * x + (eta / b) * ell_rmatvec(bundle, u).astype(x.dtype), None
-
-    x, _ = jax.lax.scan(bundle_step, x, jnp.arange(bundles))
+    x, _ = jax.lax.scan(drain, x, jnp.arange(d))
     return x
 
 
 def _one_round(tp, x, r, eta, sched):
-    """One outer round: τ inner iterations per row team + the p_r-team
-    average. The single shared round body — the monolithic scan and the
-    chunked session path both close over exactly this function, so the
-    two cannot drift (and stay bitwise-identical)."""
+    """One outer round: ``round_body`` on each row team + the p_r-team
+    average. The monolithic scan and the chunked session path both scan
+    this function, so the two stay bitwise-identical."""
 
     def team(args):
         idx, val = args
-        return _team_inner_iterations(idx, val, tp.n, x, r, eta, sched, tp.objective)
+        return round_body(idx, val, x, r, n=tp.n, sched=sched, eta=eta,
+                          objective=tp.objective, comm=COUNTING)
 
     if sched.s == 1 and not sched.delay:
         # FedAvg/MB-SGD corner: per-team working set is one (b, w)
-        # batch — run all teams batched (the old run_fedavg vmap).
-        # The delayed path materializes the full (G, v) even at s = 1
-        # (its distributed twin psums the dense block), so it takes the
-        # sequential branch like every Gram-bearing schedule.
+        # batch — run all teams batched. A delayed schedule stages a
+        # D-deep FIFO per team, so it takes the sequential branch like
+        # every Gram-bearing schedule.
         xs = jax.vmap(team)((tp.indices, tp.values))
     else:
         # lax.map (not vmap): teams run sequentially on one device,

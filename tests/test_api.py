@@ -1,6 +1,6 @@
 """The repro.api front door: spec round-tripping, plan parity with the
-raw cost model, run() dispatch, autotune, and the deprecation shims on
-the legacy distributed entry points.
+raw cost model, run() dispatch, autotune, and the shard_map step
+against the simulated engine.
 
 Multi-device shard_map runs live in test_distributed_subprocess.py;
 here the shard_map backend is exercised on the 1×1 mesh the single CPU
@@ -12,7 +12,6 @@ import json
 import os
 import subprocess
 import sys
-import warnings
 from pathlib import Path
 
 import numpy as np
@@ -228,12 +227,13 @@ def test_sstep_loss_every_must_divide():
     assert ParallelSGDSchedule.sstep(4, 8, 0.05, 64).loss_every == 0
 
 
-# ---------------- satellite: legacy distributed shims ----------------
+# ---------------- the shard_map step on a 1×1 mesh ----------------
 
 
 @pytest.fixture()
 def tiny_2d():
     from repro.core.distributed import build_2d_problem
+    from repro.core.teams import stack_row_teams
     from repro.sparse.synthetic import make_skewed_csr
     from repro import compat
 
@@ -242,54 +242,28 @@ def tiny_2d():
     y = np.where(rng.random(64) < 0.5, 1.0, -1.0)
     prob, cp = build_2d_problem(a, y, 1, 1, "cyclic", row_multiple=8)
     mesh = compat.make_mesh((1, 1), ("rows", "cols"))
-    return mesh, prob, cp
+    return mesh, prob, cp, stack_row_teams(a, y, 1, row_multiple=8)
 
 
-def test_run_hybrid_distributed_legacy_scalars_warn(tiny_2d):
-    from repro.core.distributed import run_hybrid_distributed
+@pytest.mark.parametrize("delay", [0, 1])
+@pytest.mark.parametrize("s", [1, 2])
+def test_make_hybrid_step_matches_engine(tiny_2d, s, delay):
+    """The shard_map step, round by round on the 1×1 mesh, gives the
+    simulated engine's iterates: both run the engine's round body, here
+    once with the mesh collectives and once with the counting ones."""
+    from repro.core.distributed import gather_x, make_hybrid_step, scatter_x
 
-    mesh, prob, cp = tiny_2d
-    sched = ParallelSGDSchedule.hybrid(1, 2, 4, 0.05, 4, rounds=2, gram="blocked")
-    x_new, losses = run_hybrid_distributed(mesh, prob, cp, np.zeros(50, np.float32), sched)
-    assert losses.shape == (0,)
-
-    with pytest.warns(DeprecationWarning):
-        x_pos = run_hybrid_distributed(
-            mesh, prob, cp, np.zeros(50, np.float32), 2, 4, 0.05, 4, 2
-        )
-    with pytest.warns(DeprecationWarning):
-        x_kw = run_hybrid_distributed(
-            mesh, prob, cp, np.zeros(50, np.float32), s=2, b=4, eta=0.05, tau=4, rounds=2
-        )
-    # old contract: bare x, same numerics as the schedule path
-    np.testing.assert_array_equal(x_pos, x_new)
-    np.testing.assert_array_equal(x_kw, x_new)
-
-
-def test_distributed_rejects_schedule_plus_scalars(tiny_2d):
-    """A scalar knob alongside a schedule would be silently ignored —
-    must be a hard error instead."""
-    from repro.core.distributed import make_hybrid_step, run_hybrid_distributed
-
-    mesh, prob, cp = tiny_2d
-    sched = ParallelSGDSchedule.hybrid(1, 2, 4, 0.05, 4, rounds=2, gram="blocked")
-    with pytest.raises(TypeError, match="gram"):
-        make_hybrid_step(mesh, prob, sched, gram="dense")
-    with pytest.raises(TypeError, match="rounds"):
-        run_hybrid_distributed(mesh, prob, cp, np.zeros(50, np.float32), sched, rounds=10)
-
-
-def test_make_hybrid_step_legacy_scalars_warn(tiny_2d):
-    from repro.core.distributed import make_hybrid_step
-
-    mesh, prob, _cp = tiny_2d
-    with pytest.warns(DeprecationWarning):
-        step = make_hybrid_step(mesh, prob, 2, 4, 4, 0.05)
-    assert callable(step)
-    with pytest.raises(TypeError):
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            make_hybrid_step(mesh, prob)  # neither schedule nor scalars
+    mesh, prob, cp, tp = tiny_2d
+    sched = ParallelSGDSchedule.hybrid(1, s, 4, 0.05, 4, rounds=3, gram="blocked",
+                                       delay=delay)
+    x_sim, _ = run_parallel_sgd(tp, jnp.zeros(50), sched)
+    step = make_hybrid_step(mesh, prob, sched)
+    x_pad = jnp.asarray(scatter_x(np.zeros(50, np.float32), cp, prob.n_loc))
+    for r in range(sched.rounds):
+        x_pad = step(prob.indices, prob.values, x_pad, jnp.int32(r))
+    x_mesh = gather_x(np.asarray(x_pad), cp, prob.n_loc, prob.n)
+    assert np.abs(x_mesh).max() > 0  # the rounds moved the weights
+    np.testing.assert_allclose(x_mesh, np.asarray(x_sim), rtol=0, atol=1e-5)
 
 
 def test_interpret_is_not_a_schedule_or_spec_field():

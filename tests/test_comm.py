@@ -148,9 +148,9 @@ def test_mesh_and_engine_capture_identical_rates(p_r, p_c):
 
 
 def test_s1_corner_counts_full_bundle_payload():
-    """At s=1 the simulated body only materializes v, but the mesh body
-    psums the full (G, v) — the engine pins its counted payload to the
-    same b²+b words so the two ledgers cannot disagree at the corner."""
+    """At s=1 the round body computes v alone but still reduces a zero
+    (b, b) G beside it, on both backends, so both ledgers count the
+    b²+b words of the Table 3 payload from its real shape."""
     rng = np.random.default_rng(0)
     a = make_skewed_csr(64, 40, 6, 0.8, seed=3)
     y = np.where(rng.random(64) < 0.5, 1.0, -1.0)

@@ -39,6 +39,7 @@ def test_hybrid_distributed_matches_simulated(partitioner, mesh_shape):
         from repro.sparse.synthetic import make_skewed_csr
         from repro.core.teams import stack_row_teams
         from repro.core.hybrid import run_hybrid_sgd
+        from repro.core import ParallelSGDSchedule
         from repro.core.distributed import build_2d_problem, run_hybrid_distributed
 
         rng = np.random.default_rng(0)
@@ -50,8 +51,8 @@ def test_hybrid_distributed_matches_simulated(partitioner, mesh_shape):
         tp = stack_row_teams(A, y, p_r, row_multiple=s * b)
         x_sim, _ = run_hybrid_sgd(tp, jnp.zeros(100), s, b, eta, tau, rounds)
         prob, cp = build_2d_problem(A, y, p_r, p_c, "{partitioner}", row_multiple=s * b)
-        x_dist = run_hybrid_distributed(mesh, prob, cp, np.zeros(100, np.float32),
-                                        s, b, eta, tau, rounds)
+        sched = ParallelSGDSchedule.hybrid(p_r, s, b, eta, tau, rounds, gram="blocked")
+        x_dist, _ = run_hybrid_distributed(mesh, prob, cp, np.zeros(100, np.float32), sched)
         diff = float(np.abs(np.asarray(x_sim) - x_dist).max())
         assert diff < 1e-5, diff
         print("OK", diff)
@@ -69,6 +70,7 @@ def test_distributed_fedavg_corner():
         from repro.sparse.synthetic import make_skewed_csr
         from repro.core.teams import stack_row_teams
         from repro.core.fedavg import run_fedavg
+        from repro.core import ParallelSGDSchedule
         from repro.core.distributed import build_2d_problem, run_hybrid_distributed
 
         rng = np.random.default_rng(0)
@@ -79,8 +81,8 @@ def test_distributed_fedavg_corner():
         tp = stack_row_teams(A, y, 8, row_multiple=b)
         x_f, _ = run_fedavg(tp, jnp.zeros(100), b, eta, tau, rounds)
         prob, cp = build_2d_problem(A, y, 8, 1, "rows", row_multiple=b)
-        x_d = run_hybrid_distributed(mesh, prob, cp, np.zeros(100, np.float32),
-                                     1, b, eta, tau, rounds)
+        sched = ParallelSGDSchedule.fedavg(8, b, eta, tau, rounds, gram="blocked")
+        x_d, _ = run_hybrid_distributed(mesh, prob, cp, np.zeros(100, np.float32), sched)
         diff = float(np.abs(np.asarray(x_f) - x_d).max())
         assert diff < 1e-5, diff
         print("OK", diff)
@@ -242,7 +244,7 @@ def test_comm_ledger_backend_parity(mesh_shape):
 @pytest.mark.parametrize("delay", [1, 2])
 def test_delayed_pipeline_backend_parity(delay):
     """The DaSGD delay-D pipeline on a real 2×4 mesh: both executors run
-    the shared ``delayed_bundle_scan`` (issue at bundle t, consume at
+    the engine's one ``round_body`` (reduce at bundle t, consume at
     t+D, drain before the round's parameter average), so the stale
     iterates must agree across backends — and must differ from the
     synchronous D=0 trajectory (the knob is real)."""

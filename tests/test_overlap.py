@@ -2,21 +2,23 @@
 
 Contracts under test:
 
-* D=0 run through the delay pipeline (an empty staging FIFO) is
-  bitwise the synchronous bundle scan, in the same process, so no
-  refactor of either can silently move the synchronous trajectory;
+* D=0 through the one round body is bitwise a per-bundle reference
+  written from Algorithm 3, so no change to the body can silently move
+  the synchronous trajectory;
 * D ≥ 1 changes the iterates (it is a real staleness knob) but still
   converges, monolithic and chunked execution stay bitwise at any D,
   and the ledger's counted volume is invariant in D (overlap hides
   time, not bytes);
 * the ledger's exposed/total/efficiency closed form, the Eq. 4 overlap
   pricing (max(comm, compute) per bundle) + recommend_delay, the
-  issue/await span split, spec serialization compatibility, and the
-  decaying-τ compensation schedule (One-Shot Averaging).
+  exposed-comm split of a timed run, spec serialization
+  compatibility, and the decaying-τ compensation schedule (One-Shot
+  Averaging).
 """
 
 import dataclasses
 import json
+from functools import partial
 from pathlib import Path
 
 import numpy as np
@@ -31,13 +33,16 @@ from repro.api.session import Session
 from repro.core.comm import CommLedger, CommRate
 from repro.core.engine import (
     ParallelSGDSchedule,
-    _team_inner_iterations,
-    delayed_bundle_scan,
+    bundle_gram_v,
+    inner_corrections,
+    round_body,
     run_parallel_sgd,
 )
+from repro.core.objective import LOGISTIC, get_objective
 from repro.core.teams import stack_row_teams
 from repro.costmodel.hockney import HybridConfig, hybrid_epoch_cost, recommend_delay
 from repro.costmodel.machines import MACHINES
+from repro.sparse.ell import EllBlock, ell_matvec, ell_rmatvec
 from repro.sparse.synthetic import make_skewed_csr
 
 
@@ -54,51 +59,65 @@ def _hybrid_sched(delay=0):
     )
 
 
-# ---- D=0: the delay pipeline is bitwise the synchronous scan ----
+# ---- D=0: the round body is Algorithm 3, bundle by bundle ----
 
 
-def _team_rounds_sync_vs_delay0(sched, rounds=3):
-    """One team's iterate after ``rounds`` rounds of τ inner iterations,
-    once through the synchronous bundle scan and once through
-    ``delayed_bundle_scan`` at D = 0, from the same start."""
-    assert sched.delay == 0  # the default stays synchronous
+def _reference_round(idx, val, n, x, r, eta, sched, objective):
+    """One team's round written out from Algorithm 3: for each of the
+    τ/s bundles, the cyclic slice, (G, v) (v = Yx alone at s = 1, where
+    G corrects nothing), the corrections, Yᵀu and, under L2, the ρ^s
+    decay fold."""
+    s, b = sched.s, sched.b
+    sb, bundles = s * b, sched.tau // s
+    for t in range(bundles):
+        start = ((r * bundles + t) * sb) % idx.shape[0]
+        blk = EllBlock(indices=idx[start:start + sb], values=val[start:start + sb], n=n)
+        if s == 1:
+            v = ell_matvec(blk, x)
+            g = jnp.zeros((sb, sb), v.dtype)
+        else:
+            g, v = bundle_gram_v(blk.indices, blk.values, x, n, gram=sched.gram,
+                                 bk=sched.bk, bm=sched.bm, precision=sched.precision)
+        u = inner_corrections(g, v, s, b, eta, objective)
+        upd = (eta / b) * ell_rmatvec(blk, u)
+        if objective.l2:
+            x = jnp.asarray(1.0 - eta * objective.l2, x.dtype) ** s * x + upd
+        else:
+            x = x + upd
+    return x
+
+
+DELAY0_CASES = {
+    "hybrid": (_hybrid_sched(), LOGISTIC),
+    "fedavg": (ParallelSGDSchedule.fedavg(4, 4, 0.05, 8, rounds=3), LOGISTIC),
+    "sstep-l2": (ParallelSGDSchedule.sstep(4, 4, 0.05, 12),
+                 get_objective("logistic", l2=1e-2)),
+    "squared-hinge": (_hybrid_sched(), get_objective("squared_hinge")),
+}
+
+
+@pytest.mark.parametrize("case", list(DELAY0_CASES))
+def test_delay0_round_body_bitwise_vs_algorithm3(case):
+    """At D = 0 the one round body carries no FIFO and applies each
+    bundle as it is reduced: bitwise the per-bundle reference, over
+    three rounds of one team, so no change to the body can move the
+    synchronous trajectory."""
+    sched, objective = DELAY0_CASES[case]
     a, y = _ref_problem()
-    tp = stack_row_teams(a, y, sched.p_r, row_multiple=sched.s * sched.b)
+    tp = stack_row_teams(a, y, sched.p_r, row_multiple=sched.s * sched.b,
+                         objective=objective)
     idx, val = tp.indices[0], tp.values[0]
-    sb, bundles = sched.s * sched.b, sched.tau // sched.s
     eta = jnp.float32(sched.eta)
 
-    @jax.jit
-    def sync(x, r):
-        return _team_inner_iterations(idx, val, tp.n, x, r, eta, sched, tp.objective)
-
-    @jax.jit
-    def delay0(x, r):
-        def slice_bundle(t):
-            start = ((r * bundles + t) * sb) % idx.shape[0]
-            return (jax.lax.dynamic_slice_in_dim(idx, start, sb, axis=0),
-                    jax.lax.dynamic_slice_in_dim(val, start, sb, axis=0))
-
-        return delayed_bundle_scan(x, slice_bundle=slice_bundle, bundles=bundles,
-                                   n=tp.n, sched=sched, eta=eta,
-                                   objective=tp.objective)
-
-    xs = xd = jnp.zeros(tp.n)
-    for r in range(rounds):
-        xs, xd = sync(xs, r), delay0(xd, r)
-    assert float(jnp.abs(xs).max()) > 0  # the rounds moved the weights
-    return np.asarray(xs), np.asarray(xd)
-
-
-def test_delay0_hybrid_bitwise_vs_pinned_reference():
-    xs, xd = _team_rounds_sync_vs_delay0(_hybrid_sched())
-    np.testing.assert_array_equal(xd, xs)
-
-
-def test_delay0_fedavg_bitwise_vs_pinned_reference():
-    sched = ParallelSGDSchedule.fedavg(4, 4, 0.05, 8, rounds=3, loss_every=1)
-    xs, xd = _team_rounds_sync_vs_delay0(sched)
-    np.testing.assert_array_equal(xd, xs)
+    body = jax.jit(lambda x, r: round_body(idx, val, x, r, n=tp.n, sched=sched, eta=eta,
+                                           objective=objective))
+    ref = jax.jit(partial(_reference_round, idx, val, tp.n, eta=eta, sched=sched,
+                          objective=objective), static_argnums=1)
+    xb = xr = jnp.zeros(tp.n)
+    for r in range(3):
+        xb, xr = body(xb, r), ref(xr, r)
+    assert float(jnp.abs(xb).max()) > 0  # the rounds moved the weights
+    np.testing.assert_array_equal(np.asarray(xb), np.asarray(xr))
 
 
 # ---- D ≥ 1: real staleness, still converges, chunking stays bitwise ----
